@@ -48,6 +48,11 @@ import os
 import sys
 import time
 
+# Peak HBM bandwidth (GB/s) per chip, keyed by jax ``device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (16 GB HBM at 819 GB/s).
+# A device missing here is an error, never a default.
+HBM_PEAK_GBPS = {"TPU v5 lite": 819.0}
+
 BASELINE_BRACKET_MIPS = (5.0, 20.0, 50.0)
 BASELINE_MIPS = 20.0
 NUM_TILES = 64
@@ -58,6 +63,15 @@ DEFAULT_BUDGET_S = 1200.0
 
 TELEMETRY_DIR = os.environ.get("GRAPHITE_BENCH_TELEMETRY_DIR",
                                "bench_telemetry")
+
+
+def hbm_peak_gbps(device_kind: str) -> float:
+    try:
+        return HBM_PEAK_GBPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HBM peak on record for device kind {device_kind!r}: add "
+            f"it, with its source, to bench.HBM_PEAK_GBPS") from None
 
 
 def _synth_cached(name, fn, **kwargs):
@@ -155,9 +169,13 @@ def _run(trace_fn, num_tiles: int, max_steps=None, label=None, **overrides):
     state_bytes = sum(
         leaf.size * leaf.dtype.itemsize
         for leaf in jax.tree_util.tree_leaves(sim.state))
-    hbm_peak_gbps = 819.0          # v5e HBM bandwidth
-    hbm_util = (state_bytes * rounds / max(host_s, 1e-9)) \
-        / (hbm_peak_gbps * 1e9)
+    # The HBM share exists only on a TPU (an unknown TPU kind is an
+    # error); off the TPU the row keeps its other metrics and says null.
+    dev = jax.devices()[0]
+    hbm_util = None
+    if dev.platform == "tpu":
+        hbm_util = round((state_bytes * rounds / max(host_s, 1e-9))
+                         / (hbm_peak_gbps(dev.device_kind) * 1e9), 5)
     row = {
         "kind": "completed" if completed else "throughput_probe",
         "num_tiles": num_tiles,
@@ -177,7 +195,7 @@ def _run(trace_fn, num_tiles: int, max_steps=None, label=None, **overrides):
         "ms_per_round": round(host_s / max(rounds, 1) * 1e3, 3),
         "state_bytes": state_bytes,
         "hbm_bytes_per_sec": round(state_bytes * rounds / max(host_s, 1e-9)),
-        "hbm_utilization_vs_peak": round(hbm_util, 5),
+        "hbm_utilization_vs_peak": hbm_util,
         "completion_time_ns": d["completion_time_ns"],
         "device_steps": sim.steps,
         "all_done": completed,
@@ -289,9 +307,6 @@ def _structural_row(main_run):
     cfg = load_config()
     cfg.set("general/total_cores", NUM_TILES)
     cfg.set("tpu/miss_chain", 12)
-    # Pin both modes explicitly: the default "auto" resolves to the
-    # KERNEL path on a TPU backend, which would turn the off-vs-on
-    # comparison into self-comparison exactly where it matters.
     p_off = dataclasses.replace(SimParams.from_config(cfg),
                                 pallas_kernels="off")
     p_on = dataclasses.replace(p_off, pallas_kernels="interpret")
@@ -533,7 +548,8 @@ def _pad_trace(trace):
 
 def _captured_row(name: str):
     """Build + run + simulate one captured benchmark; returns a bench row,
-    a skip marker, or None when the reference tree is absent."""
+    or None when the reference tree is absent.  A capture or simulation
+    error propagates (the row fails)."""
     import os
     import subprocess
     import sys
@@ -595,38 +611,30 @@ def _captured_row(name: str):
             with obs.span(f"{name}.trace_load"):
                 return _pad_trace(load_binary_trace(trace_path))
 
-    try:
-        from graphite_tpu.events import trace_cache
-        # Key includes the CONTENT of the vendored sources/headers and
-        # the capture toolchain, not just their names — an edited
-        # benchmark source or frontend change re-captures.
-        srcs = [os.path.join(bench_root, rel)
-                for rel in spec["srcs"] + spec.get("headers", [])]
-        tools = [os.path.join(repo, "tools", t)
-                 for t in ("capture_build.sh", "annotate_trace.py",
-                           "splash_m4.py")]
-        trace = trace_cache.cached(
-            ("captured", name, spec["srcs"], spec["args"],
-             spec.get("tiles", 64), sorted(spec.get("env", {}).items()),
-             spec.get("stdin", "")),
-            _capture_build, src_files=srcs + tools + [macros])
-    except Exception as e:   # missing toolchain, capture failure, ...
-        return {"kind": "skipped", "reason": str(e)[:200]}
-    try:
-        row = _run(lambda T: trace, trace.num_tiles,
-                   label=f"{name}_captured",
-                   **{"general/trigger_models_within_application": "true",
-                      "tpu/cond_replay": "true"})
-    except Exception as e:   # device OOM on an oversize capture, ...
-        return {"kind": "skipped", "reason": str(e)[:200]}
+    from graphite_tpu.events import trace_cache
+    # Key includes the CONTENT of the vendored sources/headers and
+    # the capture toolchain, not just their names — an edited
+    # benchmark source or frontend change re-captures.
+    srcs = [os.path.join(bench_root, rel)
+            for rel in spec["srcs"] + spec.get("headers", [])]
+    tools = [os.path.join(repo, "tools", t)
+             for t in ("capture_build.sh", "annotate_trace.py",
+                       "splash_m4.py")]
+    trace = trace_cache.cached(
+        ("captured", name, spec["srcs"], spec["args"],
+         spec.get("tiles", 64), sorted(spec.get("env", {}).items()),
+         spec.get("stdin", "")),
+        _capture_build, src_files=srcs + tools + [macros])
+    row = _run(lambda T: trace, trace.num_tiles,
+               label=f"{name}_captured",
+               **{"general/trigger_models_within_application": "true",
+                  "tpu/cond_replay": "true"})
     row["workload"] = f"SPLASH-2 {name} (captured, unmodified source)"
     return row
 
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
-    from graphite_tpu.compile_cache import enable_compile_cache
-    enable_compile_cache()
     if "--help" in argv or "-h" in argv:
         print(__doc__)
         print(f"env: GRAPHITE_BENCH_BUDGET_S   wall-clock budget in "
@@ -693,11 +701,13 @@ def main(argv=None) -> int:
         return row
 
     def safe(key, fn, optional=False):
-        """One broken row must not void the whole benchmark (the r4
-        bench died whole and left the round numberless), and one SLOW
-        row must not overrun the driver timeout (the r4/r5 rc=124).
-        ``optional`` rows may return None (workload unavailable in this
-        container) and then leave no detail entry at all."""
+        """One broken row must not stop the later rows (the r4 bench
+        died whole and left the round numberless), and one SLOW row must
+        not overrun the driver timeout (the r4/r5 rc=124).  A broken row
+        is recorded as ``kind: failed`` and makes the bench exit
+        non-zero at the end.  ``optional`` rows may return None
+        (workload unavailable in this container) and then leave no
+        detail entry at all."""
         spent = time.monotonic() - t_start
         if spent >= budget_s:
             det[key] = {"kind": "skipped_budget",
@@ -707,7 +717,8 @@ def main(argv=None) -> int:
             try:
                 row = fn()
             except Exception as e:
-                row = {"kind": "failed", "reason": str(e)[:200]}
+                row = {"kind": "failed",
+                       "reason": f"{type(e).__name__}: {str(e)[:200]}"}
             if row is None and optional:
                 return
             det[key] = row
@@ -858,17 +869,20 @@ def main(argv=None) -> int:
 
     def shard8_ab():
         """Round-11 scale-out A/B: the radix1024 shape with
-        ``tpu/tile_shards = 8`` vs 1, in a fresh 8-device subprocess
-        (this process does not force virtual devices) — reports
-        quanta_per_s for both legs and the bit-identity flag.  On CPU
-        the sharded leg prices loopback-collective rendezvous, so the
-        ratio bounds coordination overhead from above; the same row on
-        a TPU slice is the real scale-out number (PROFILE.md r11)."""
+        ``tpu/tile_shards = 8`` vs 1, in THIS process on the devices it
+        sees (a chip belongs to one process, so no child can take it) —
+        reports quanta_per_s for both legs and the bit-identity flag.
+        Fewer than 8 devices skips the row and says so."""
+        import jax
+        if len(jax.devices()) < 8:
+            return {"kind": "skipped",
+                    "reason": f"needs 8 devices; jax sees "
+                              f"{len(jax.devices())} "
+                              f"({jax.devices()[0].platform})"}
         sys.path.insert(0, os.path.join(
             os.path.dirname(os.path.abspath(__file__)), "tools"))
-        from weak_scaling import bench_shard8_row
-        remaining = max(budget_s - (time.monotonic() - t_start), 60.0)
-        return bench_shard8_row(tiles=1024, timeout=remaining)
+        from weak_scaling import shard_ab_row
+        return shard_ab_row(8, tiles=1024)
 
     safe("radix1024_shard8", shard8_ab)
     # BASELINE config 2: directory-MSI coherence stress at 256 tiles,
@@ -890,6 +904,11 @@ def main(argv=None) -> int:
         safe(f"{name}{tiles}_captured",
              lambda name=name: _captured_row(name), optional=True)
     emit()
+    failed = [k for k, v in det.items()
+              if isinstance(v, dict) and v.get("kind") == "failed"]
+    if failed:
+        print(f"bench rows failed: {', '.join(failed)}", file=sys.stderr)
+        return 1
     return 0
 
 

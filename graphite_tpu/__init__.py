@@ -32,19 +32,17 @@ import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
 
-# Persistent XLA compilation cache: the fused quantum step is a large XLA
-# program (tens of seconds per unique (params, shapes) key); caching makes
-# repeated bench/test/CLI invocations compile-free.  Honors an explicit
-# JAX_COMPILATION_CACHE_DIR; otherwise uses <repo>/.jax_cache — only when
-# the package actually sits in a repo checkout (pyproject.toml beside it),
-# so a site-packages install does not grow a cache inside the environment.
+# Persistent XLA compilation cache, one policy for every entry point:
+# JAX_COMPILATION_CACHE_DIR, when set, is the cache (jax reads it itself
+# and nothing here overrides it).  Otherwise a checkout (pyproject.toml
+# beside the package) keeps it at the fixed path <checkout>/.jax_cache —
+# the path is part of the cache key, so a moving directory never hits;
+# an installed package keeps none.
 if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     _root = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
     if _os.path.exists(_os.path.join(_root, "pyproject.toml")):
         _jax.config.update("jax_compilation_cache_dir",
                            _os.path.join(_root, ".jax_cache"))
-_jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-_jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 __version__ = "0.1.0"
 

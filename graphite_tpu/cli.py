@@ -113,8 +113,6 @@ def _build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[List[str]] = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
-    from graphite_tpu.compile_cache import enable_compile_cache
-    enable_compile_cache()
     overrides, rest = parse_overrides(argv)
     args = _build_parser().parse_args(rest)
     telemetry_dir = getattr(args, "telemetry_dir", None)

@@ -2,16 +2,16 @@
 
 ``tpu/pallas_kernels`` selects per-phase execution:
 
-  * ``off``       — the untouched lax path (CPU default: XLA:CPU has no
-                    per-op dispatch cost to amortize, and Mosaic cannot
-                    lower there anyway).
+  * ``off``       — the untouched lax path, the default everywhere:
+                    Mosaic refuses the kernels' int64 operands (ROADMAP
+                    A.1), so no backend lowers them today.
   * ``interpret`` — ``pl.pallas_call(..., interpret=True)``: the same
                     kernel body evaluated by the Pallas interpreter on
                     any backend.  This is the CPU-testable path the
                     bit-identity gate runs.
-  * ``tpu``       — real Mosaic lowering (one custom-call per phase).
-  * ``auto``      — ``tpu`` when the default jax backend is TPU, else
-                    ``off``.
+  * ``on``        — real Mosaic lowering (one custom-call per phase),
+                    resolved to ``tpu``.  A refusal surfaces as the
+                    compiler's own error; nothing falls back to lax.
 
 Phase support is gated here (``window_mode`` / ``chain_mode``): a config
 the kernels do not cover (iocoom cores, non-divisible tile blocks) falls
@@ -28,6 +28,7 @@ ride as (1, 1) operands (SMEM-shaped for the TPU path).
 from __future__ import annotations
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from graphite_tpu.params import SimParams
@@ -36,11 +37,7 @@ from graphite_tpu.params import SimParams
 def kernels_mode(params: SimParams) -> str:
     """Resolve ``tpu/pallas_kernels`` to 'off' | 'interpret' | 'tpu'."""
     v = params.pallas_kernels
-    if v == "auto":
-        return "tpu" if jax.default_backend() == "tpu" else "off"
-    if v == "on":
-        return "tpu"
-    return v
+    return "tpu" if v == "on" else v
 
 
 def tile_block(num_tiles: int, cap: int = 128) -> int:
@@ -242,14 +239,14 @@ def jaxpr_op_counts(fn, *args) -> dict:
     counts = {"eqns": 0, "gather": 0, "scatter": 0, "pallas_call": 0,
               "while": 0, "fori_or_scan": 0, "collective": 0}
 
-    # Cross-device communication primitives (startswith, to catch the
-    # psum/psum2 and reduce_scatter naming variants across jax versions).
+    # Cross-device communication primitives, matched by prefix so each
+    # family's variants (psum / psum_invariant, ...) count together.
     # The round-11 sharding gates assert the shard-LOCAL window phase has
     # zero of these and the whole sharded step a small bounded count.
     # Each family is ALSO counted under its own key (zero-initialized so
     # absent families read 0): the round-15 resident gate asserts the
     # per-family budget — zero all_gathers, a bounded all_to_all count,
-    # exactly one pmin — not just the total.
+    # the barrier's two int32 pmin words — not just the total.
     _COLLECTIVES = ("all_gather", "psum", "pmin", "pmax", "all_to_all",
                     "ppermute", "reduce_scatter", "pbroadcast")
     for fam in _COLLECTIVES:
@@ -284,9 +281,9 @@ def jaxpr_op_counts(fn, *args) -> dict:
                         visit(sub)
 
     def _subjaxprs_of(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             return [v.jaxpr]
-        if isinstance(v, jax.core.Jaxpr):
+        if isinstance(v, jex_core.Jaxpr):
             return [v]
         if isinstance(v, (list, tuple)):
             out = []

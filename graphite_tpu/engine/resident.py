@@ -754,7 +754,8 @@ def _advance(params: SimParams, vp: VariantParams, st: SimState,
 def _begin_quantum(params: SimParams, vp: VariantParams,
                    st: SimState) -> SimState:
     """quantum.next_boundary, resident form: the min-reduction is the
-    shard-local min followed by the ONE pmin — the quantum barrier."""
+    shard-local min followed by the ONE int64 pmin — the quantum barrier
+    (two int32 pmin words, meshmod.pmin_i64)."""
     blocked = jnp.zeros_like(st.done)
     for k in _SYNC_PENDS:
         blocked = blocked | (st.pend_kind == k)
@@ -763,7 +764,7 @@ def _begin_quantum(params: SimParams, vp: VariantParams,
     if params.miss_chain > 0 and params.fanout_replay:
         clk = jnp.where(st.mq_head > 0, jnp.maximum(clk, st.chain_base), clk)
     masked = jnp.where(runnable, clk, TIME_MAX)
-    mn = jax.lax.pmin(jnp.min(masked), TILE_AXIS)
+    mn = meshmod.pmin_i64(jnp.min(masked))
     q = vp.quantum_ps
     nb = (mn // q + 1) * q
     any_run = _psum(jnp.sum(runnable.astype(jnp.int32))) > 0
@@ -797,7 +798,6 @@ def _programs(params: SimParams, state: SimState,
     hit = _CACHE.get(key)
     if hit is not None:
         return hit
-    from jax.experimental.shard_map import shard_map
     devices = jax.devices()
     if len(devices) < shards:
         raise ConfigError(
@@ -812,20 +812,20 @@ def _programs(params: SimParams, state: SimState,
     tr_specs = meshmod.resident_specs(trace, T)
     flag_specs = {k: P_spec() for k in _FLAG_KEYS}
 
-    begin = jax.jit(shard_map(
+    begin = jax.jit(jax.shard_map(
         lambda s: _begin_quantum(params, vp, s), mesh=mesh,
-        in_specs=(st_specs,), out_specs=st_specs, check_rep=False))
-    advance = jax.jit(shard_map(
+        in_specs=(st_specs,), out_specs=st_specs, check_vma=False))
+    advance = jax.jit(jax.shard_map(
         lambda s, tr: _advance(params, vp, s, tr, shards), mesh=mesh,
-        in_specs=(st_specs, tr_specs), out_specs=st_specs, check_rep=False))
-    resolve = jax.jit(shard_map(
+        in_specs=(st_specs, tr_specs), out_specs=st_specs, check_vma=False))
+    resolve = jax.jit(jax.shard_map(
         lambda s: _resolve_subround(params, vp, s, shards, cap), mesh=mesh,
         in_specs=(st_specs,), out_specs=(st_specs, flag_specs),
-        check_rep=False))
-    spill = jax.jit(shard_map(
+        check_vma=False))
+    spill = jax.jit(jax.shard_map(
         lambda s: _resolve_subround(params, vp, s, 1, 2 * T), mesh=mesh1,
         in_specs=(st_specs,), out_specs=(st_specs, flag_specs),
-        check_rep=False))
+        check_vma=False))
     stuck = jax.jit(lambda s: resolvemod.resolve_memory(params, vp, s))
 
     pg = _Programs(mesh=mesh, mesh1=mesh1, shards=shards, cap=cap,
@@ -979,7 +979,6 @@ def _sweep_programs(params: SimParams, bstate: SimState, trace: TraceArrays,
     hit = _SWEEP_CACHE.get(key)
     if hit is not None:
         return hit
-    from jax.experimental.shard_map import shard_map
     devices = jax.devices()
     if len(devices) < shards:
         raise ConfigError(
@@ -1011,16 +1010,16 @@ def _sweep_programs(params: SimParams, bstate: SimState, trace: TraceArrays,
             lambda s, v: _resolve_subround(params, v, s, shards, cap))(bs, bv)
         return _lane_select(run, new, bs), flags
 
-    begin = jax.jit(shard_map(beg, mesh=mesh,
+    begin = jax.jit(jax.shard_map(beg, mesh=mesh,
                               in_specs=(bst_specs, bvp_specs, run_spec),
-                              out_specs=bst_specs, check_rep=False))
-    advance = jax.jit(shard_map(
+                              out_specs=bst_specs, check_vma=False))
+    advance = jax.jit(jax.shard_map(
         adv, mesh=mesh,
         in_specs=(bst_specs, tr_specs, bvp_specs, run_spec),
-        out_specs=bst_specs, check_rep=False))
-    resolve = jax.jit(shard_map(
+        out_specs=bst_specs, check_vma=False))
+    resolve = jax.jit(jax.shard_map(
         res, mesh=mesh, in_specs=(bst_specs, bvp_specs, run_spec),
-        out_specs=(bst_specs, flag_specs), check_rep=False))
+        out_specs=(bst_specs, flag_specs), check_vma=False))
     stuck = jax.jit(jax.vmap(
         lambda s, v: resolvemod.resolve_memory(params, v, s)))
 
@@ -1106,17 +1105,16 @@ def sweep_megarun(params: SimParams, bstate: SimState, trace: TraceArrays,
 
 # ===================================================== collective census
 
-def lowered_quantum_collectives(params: SimParams, state: SimState,
-                                trace: TraceArrays) -> Dict[str, int]:
-    """Op census of ONE resident quantum step (begin -> advance -> one
-    resolve sub-round) — the run_tests.sh gate input: zero all_gathers,
-    at most two all_to_alls (both inside the chain fori body), exactly
-    one pmin."""
-    from jax.experimental.shard_map import shard_map
-    from graphite_tpu.engine.kernels import dispatch as kdispatch
+def quantum_program(params: SimParams, state: SimState,
+                    trace: TraceArrays, mesh):
+    """ONE resident quantum step (begin -> advance -> one resolve
+    sub-round) shard_mapped over ``mesh``: the census input below and
+    the ahead-of-time compile target of tests/test_tpu_compile.py (a
+    mesh of described, unattached devices)."""
     _validate(params, state, trace)
-    pg = _programs(params, state, trace)
     T = params.num_tiles
+    shards = mesh.devices.size
+    cap = route_capacity(params)
     vp = variant_params(params)
     st_specs = meshmod.resident_specs(state, T)
     tr_specs = meshmod.resident_specs(trace, T)
@@ -1124,11 +1122,22 @@ def lowered_quantum_collectives(params: SimParams, state: SimState,
 
     def one(s, tr):
         s = _begin_quantum(params, vp, s)
-        s = _advance(params, vp, s, tr, pg.shards)
-        return _resolve_subround(params, vp, s, pg.shards, pg.cap)
+        s = _advance(params, vp, s, tr, shards)
+        return _resolve_subround(params, vp, s, shards, cap)
 
-    fn = shard_map(one, mesh=pg.mesh, in_specs=(st_specs, tr_specs),
-                   out_specs=(st_specs, flag_specs), check_rep=False)
+    return jax.shard_map(one, mesh=mesh, in_specs=(st_specs, tr_specs),
+                         out_specs=(st_specs, flag_specs), check_vma=False)
+
+
+def lowered_quantum_collectives(params: SimParams, state: SimState,
+                                trace: TraceArrays) -> Dict[str, int]:
+    """Op census of ONE resident quantum step — the run_tests.sh gate
+    input: zero all_gathers, at most two all_to_alls (both inside the
+    chain fori body), exactly one pmin."""
+    from graphite_tpu.engine.kernels import dispatch as kdispatch
+    pg = _programs(params, state, trace)
+    T = params.num_tiles
+    fn = quantum_program(params, state, trace, pg.mesh)
     state_p = meshmod.resident_place(state, pg.mesh, T)
     trace_p = meshmod.resident_place(trace, pg.mesh, T)
     return kdispatch.jaxpr_op_counts(fn, state_p, trace_p)

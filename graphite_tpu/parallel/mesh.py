@@ -44,10 +44,27 @@ from __future__ import annotations
 from typing import Any, Callable, Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 TILE_AXIS = "tiles"
+
+
+def pmin_i64(x: jax.Array, axis: str = TILE_AXIS) -> jax.Array:
+    """Exact ``pmin`` of int64 values over ``axis``.  The TPU compiler
+    lowers only Sum all-reduces of 64-bit integers (an s64 min is
+    refused: "Supported lowering only of Sum all reduce"), so the min
+    runs as two int32 pmins: the high words, then the biased low words
+    of the shards that hold the minimal high word.  Exact over the whole
+    int64 range."""
+    hi = (x >> 32).astype(jnp.int32)
+    lo = ((x & 0xFFFFFFFF) - 2**31).astype(jnp.int32)
+    mhi = jax.lax.pmin(hi, axis)
+    mlo = jax.lax.pmin(
+        jnp.where(hi == mhi, lo, jnp.iinfo(jnp.int32).max), axis)
+    return (mhi.astype(jnp.int64) << 32) \
+        | ((mlo.astype(jnp.int64) + 2**31) & 0xFFFFFFFF)
 
 
 def make_mesh(devices: Optional[Sequence[jax.Device]] = None,
@@ -67,12 +84,11 @@ def shard_wrap(tile_shards: int, fn: Callable, nargs: int) -> Callable:
     collectives (all_gather, the pmin barrier).  Replication also makes
     the bit-identity contract structural — each shard computes the same
     full-T arrays wherever it is not explicitly sliced.
-    ``check_rep=False`` because the engine's while_loops and explicit
-    collectives defeat the replication checker, not because anything is
+    ``check_vma=False`` because the engine's while_loops and explicit
+    collectives defeat the varying-axes checker, not because anything is
     unreplicated."""
     if tile_shards <= 1:
         return fn
-    from jax.experimental.shard_map import shard_map
     devices = jax.devices()
     if len(devices) < tile_shards:
         raise ValueError(
@@ -80,8 +96,8 @@ def shard_wrap(tile_shards: int, fn: Callable, nargs: int) -> Callable:
             f"devices; jax sees {len(devices)} (force virtual CPU "
             f"devices with --xla_force_host_platform_device_count)")
     mesh = make_mesh(devices[:tile_shards])
-    return shard_map(fn, mesh=mesh, in_specs=(P(),) * nargs,
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * nargs,
+                     out_specs=P(), check_vma=False)
 
 
 # Tile-axis position per engine array field.  Engine arrays keep small

@@ -103,7 +103,7 @@ def _fast_forward(value: int) -> int:
     return value
 
 
-_PALLAS_KERNEL_MODES = ("auto", "off", "interpret", "on")
+_PALLAS_KERNEL_MODES = ("off", "interpret", "on")
 
 
 def _pallas_kernels(value: str) -> str:
@@ -802,11 +802,12 @@ class SimParams:
     # phase as fused TPU kernels over VMEM-resident operands instead of
     # dozens of sequentially dispatched XLA ops.  A STRING so the sweep
     # zoo classifies it structural by nature:
-    #   "auto"      — real Pallas on a TPU backend, plain lax elsewhere
-    #   "off"       — always the lax reference path
+    #   "off"       — the lax reference path (default: Mosaic refuses
+    #                 the kernels' int64 operands, ROADMAP A.1)
     #   "interpret" — Pallas kernels under the interpreter (CPU-testable;
     #                 the bit-identity gate in tests/test_kernels.py)
-    #   "on"        — force real Pallas lowering (TPU only)
+    #   "on"        — real Pallas lowering; fails with the compiler's
+    #                 own error until the kernels carry no int64
     # Results are bit-identical across all values — the kernels run the
     # SAME walk/classify code on block-sliced operands (all-integer
     # arithmetic; per-tile independent), dispatched in kernels/dispatch.
@@ -1188,7 +1189,7 @@ class SimParams:
                 "tpu/max_resolve_rounds"),
             fanout_replay=cfg.get_bool("tpu/fanout_replay", True),
             pallas_kernels=_pallas_kernels(
-                cfg.get_str("tpu/pallas_kernels", "auto")),
+                cfg.get_str("tpu/pallas_kernels", "off")),
             tile_shards=_tile_shards(
                 cfg.get_str("tpu/tile_shards", "1"), T),
             shard_state=_shard_state(

@@ -13,16 +13,11 @@ if "xla_force_host_platform_device_count" not in xla_flags:
 
 import jax  # noqa: E402
 
-jax.config.update("jax_enable_x64", True)
-
-# Persistent compile cache: the engine's fused step is a large XLA program
-# (tens of seconds to compile per unique (params, shapes) key on CPU);
-# caching makes repeated suite runs compile-free.
-_CACHE_DIR = os.path.join(os.path.dirname(__file__), os.pardir,
-                          ".jax_cache")
-jax.config.update("jax_compilation_cache_dir", os.path.abspath(_CACHE_DIR))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+# x64 + the package's compile-cache policy (JAX_COMPILATION_CACHE_DIR
+# when set, else <checkout>/.jax_cache): the engine's fused step is a
+# large XLA program, so a warm cache makes repeated suite runs
+# compile-free.
+import graphite_tpu  # noqa: E402,F401
 
 import pytest  # noqa: E402
 

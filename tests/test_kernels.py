@@ -90,21 +90,25 @@ def test_interpret_bit_identity_shared_l2(chain):
     _assert_identical(a, ca, b, cb, f"sh_l2_mesi chain={chain}")
 
 
-def test_dispatch_defaults_lax_on_cpu():
-    """'auto' resolves to the lax path off-TPU (CPU pays no dispatch
-    cost, and Mosaic cannot lower there), and the gates route iocoom
-    windows to lax at any setting."""
-    import jax
-
+def test_dispatch_defaults_lax():
+    """The default is the lax path on every backend (Mosaic refuses the
+    kernels' int64 operands, ROADMAP A.1), 'auto' is gone, 'on' means
+    real lowering, and the gates route iocoom windows to lax at any
+    setting."""
+    from graphite_tpu.config import ConfigError
     from graphite_tpu.engine.kernels import dispatch
 
     cfg = load_config()
     cfg.set("general/total_cores", 2)
     params = SimParams.from_config(cfg)
-    assert params.pallas_kernels == "auto"
-    if jax.default_backend() != "tpu":
-        assert dispatch.kernels_mode(params) == "off"
-        assert dispatch.window_mode(params) == "off"
+    assert params.pallas_kernels == "off"
+    assert dispatch.kernels_mode(params) == "off"
+    assert dispatch.window_mode(params) == "off"
+    cfg.set("tpu/pallas_kernels", "auto")
+    with pytest.raises(ConfigError):
+        SimParams.from_config(cfg)
+    cfg.set("tpu/pallas_kernels", "on")
+    assert dispatch.kernels_mode(SimParams.from_config(cfg)) == "tpu"
     cfg.set("tpu/pallas_kernels", "interpret")
     cfg.set("tile/model_list", "<default,iocoom,T1,T1,T1>")
     p2 = SimParams.from_config(cfg)

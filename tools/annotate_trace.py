@@ -27,7 +27,7 @@ Usage: python tools/annotate_trace.py [--verbose] BINARY TRACE_IN [TRACE_OUT]
 Progress chatter ("N/M compute events typed") is silenced unless
 --verbose (or verbose=True): bench.py annotates one capture per row and
 the per-trace lines dominated the visible tail of a timed-out bench
-(BENCH_r05.json).  Anomalies — no static blocks decoded, annotation
+(round 5).  Anomalies — no static blocks decoded, annotation
 refused under branch thinning — always print.
 """
 

@@ -13,8 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
+import graphite_tpu  # noqa: F401,E402  (x64 + the compile-cache policy)
 
 ITERS = 300
 CALLS = 3

@@ -33,8 +33,7 @@ import time
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
+import graphite_tpu  # noqa: F401,E402  (x64 + the compile-cache policy)
 
 from graphite_tpu.config import (apply_set_overrides, load_config,
                                  split_set_overrides)
@@ -98,9 +97,7 @@ def main():
         print(f"T={T} window[{'lax' if mode == 'off' else mode}]: "
               f"{us:.0f} us/round{note}", flush=True)
 
-    # Structural evidence: the op chain the kernel absorbs.  Both modes
-    # pinned explicitly — "auto" resolves to the kernel path on a TPU
-    # backend, which would make the "off" row kernels-on there.
+    # Structural evidence: the op chain the kernel absorbs.
     p_off = dataclasses.replace(params, pallas_kernels="off")
     p_on = dataclasses.replace(params, pallas_kernels="interpret")
     for lbl, p in (("off", p_off), ("on", p_on)):

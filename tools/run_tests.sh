@@ -210,9 +210,8 @@ if [ $sweep_rc -ne 0 ]; then
 fi
 
 # Pallas-kernel smoke gate (ISSUE 10 CI satellite): (1) the dispatch
-# layer must select the lax path on CPU under the default "auto" (the
-# kernels buy nothing without per-op dispatch cost and Mosaic cannot
-# lower there); (2) a tiny-shape interpret run must be BIT-IDENTICAL to
+# layer must select the lax path under the default "off" (Mosaic
+# refuses the kernels' int64 operands, ROADMAP A.1); (2) a tiny-shape interpret run must be BIT-IDENTICAL to
 # the lax run — clocks, every counter, every phase-execution counter —
 # through the whole engine including the chain replay's classify
 # kernel; (3) the window phase with kernels on must lower to exactly
@@ -244,11 +243,10 @@ def run(mode):
     s = sim.run(max_steps=64)
     return params, sim, s
 
-p_auto = SimParams.from_config(load_config())
-assert p_auto.pallas_kernels == "auto"
-if jax.default_backend() != "tpu":
-    assert kdispatch.kernels_mode(p_auto) == "off", \
-        "auto must resolve to lax off-TPU"
+p_def = SimParams.from_config(load_config())
+assert p_def.pallas_kernels == "off"
+assert kdispatch.kernels_mode(p_def) == "off", \
+    "the default must be the lax path"
 
 pa, sa, a = run("off")
 pb, sb, b = run("interpret")
@@ -396,8 +394,8 @@ fi
 # resident quantum step (tpu/shard_state=resident over 8 virtual
 # devices) must contain ZERO full-T all_gathers, at most TWO
 # fixed-capacity all_to_alls (request + response routing legs) and
-# exactly ONE pmin (the quantum barrier).  Both censuses are recorded
-# keyed by shard strategy in results_db, whose COUNT_METRICS flag must
+# exactly ONE int64 pmin (the quantum barrier, as two int32 pmin
+# words).  Both censuses are recorded keyed by shard strategy in results_db, whose COUNT_METRICS flag must
 # fire if a resident row ever grows a collective — a full-T
 # materialization leaking back into the steady state is a 0 -> 1
 # event, not a drift.
@@ -435,7 +433,7 @@ cres = resident.lowered_quantum_collectives(
     pres, make_state(pres), tarrays)
 assert cres["all_gather"] == 0, cres
 assert cres["all_to_all"] <= 2, cres
-assert cres["pmin"] == 1, cres
+assert cres["pmin"] == 2, cres   # one int64 barrier = two int32 words
 
 prep = params("replicated")
 crep = dispatch.jaxpr_op_counts(

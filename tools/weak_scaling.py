@@ -6,8 +6,10 @@ bounded window of quanta through the EXPLICIT shard_map path
 (``tpu/tile_shards`` — parallel/mesh.shard_wrap; the GSPMD placement
 this tool used in round 7 is superseded, PROFILE.md round 11) and
 reports quanta per host second.  Every leg is its own subprocess with a
-clean jax runtime: on the CPU backend it forces exactly ``shards``
-virtual devices, on real accelerators it uses the devices jax exposes.
+clean jax runtime on the parent's platform (the parent never imports
+jax, so each leg may take the chip): with ``JAX_PLATFORMS=cpu`` it
+forces exactly ``shards`` virtual devices, otherwise it uses the
+devices jax exposes.
 
 On CPU the collectives are loopback memcpy, so the curve bounds the
 COORDINATION overhead of the sharded program from above rather than
@@ -30,7 +32,6 @@ footprint claim in measurable form).
     python tools/weak_scaling.py --shards 1,8 --tiles 1024   # subset
     python tools/weak_scaling.py --no-resident       # replicated only
     python tools/weak_scaling.py --quanta 24 --warm 8        # window
-    python tools/weak_scaling.py --bench-shard8      # bench.py's A/B row
     python tools/weak_scaling.py --leg S T           # internal (one cell)
     python tools/weak_scaling.py --leg-resident S T  # one resident cell
 
@@ -183,49 +184,38 @@ def _measure_resident(shards: int, tiles: int, quanta: int,
 
 
 def _leg_env(shards: int):
-    """Clean-runtime env for one cell: scrub the driver's jax pins (same
-    workaround as tools/multihost_dryrun.py); on CPU force exactly
-    ``shards`` virtual devices."""
+    """Env for one cell's child: the parent's platform, untouched (the
+    parent never imports jax, so the child may take the chip).  Only
+    when the caller pinned ``JAX_PLATFORMS=cpu`` does the child force
+    exactly ``shards`` virtual CPU devices."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("PYTHONPATH", "XLA_FLAGS", "PYTHONSTARTUP")}
-    env["PYTHONPATH"] = repo
-    platform = env.setdefault("JAX_PLATFORMS", "cpu")
-    if platform == "cpu":
+    env = dict(os.environ, PYTHONPATH=repo)
+    if env.get("JAX_PLATFORMS") == "cpu":
         env["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
+            env.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={shards}").strip()
     return repo, env
 
 
 def run_leg(shards: int, tiles: int, quanta: int, warm: int,
             resident: bool = False) -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
-    from graphite_tpu.compile_cache import enable_compile_cache
-    enable_compile_cache()
+    import graphite_tpu  # noqa: F401  (x64 + the compile-cache policy)
     fn = _measure_resident if resident else _measure
     print("WEAK_SCALING_ROW "
           + json.dumps(fn(shards, tiles, quanta, warm)), flush=True)
 
 
-def run_bench_shard8(tiles: int = 1024, quanta: int = QUANTA,
-                     warm: int = WARM) -> None:
-    """bench.py's ``radix1024_shard8`` A/B row: the SAME process (8
-    devices) runs the cell sharded and unsharded, reports both rates
-    and whether the final states match bit for bit."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+def shard_ab_row(shards: int, tiles: int = 1024, quanta: int = QUANTA,
+                 warm: int = WARM) -> dict:
+    """bench.py's ``radix1024_shard8`` A/B row, in the CALLER's process
+    on the devices it sees: the cell sharded over ``shards`` devices
+    and unsharded, both rates, and whether the final states match bit
+    for bit."""
     import jax
-
-    jax.config.update("jax_enable_x64", True)
-    from graphite_tpu.compile_cache import enable_compile_cache
-    enable_compile_cache()
     import jax.tree_util as jtu
     import numpy as np
 
-    sharded = _measure(8, tiles, quanta, warm)
+    sharded = _measure(shards, tiles, quanta, warm)
     single = _measure(1, tiles, quanta, warm)
 
     # Bit-identity on a short full run of the same shape (quanta-bounded
@@ -238,31 +228,31 @@ def run_bench_shard8(tiles: int = 1024, quanta: int = QUANTA,
                             radix=RADIX)
     tarrays = TraceArrays.from_trace(trace)
 
-    def short(shards):
-        p = _params(tiles, shards)
+    def short(n):
+        p = _params(tiles, n)
         st = megarun(p, make_state(p, has_capi=False), tarrays, warm)
         jax.block_until_ready(st)
         return st
 
-    s8, s1 = short(8), short(1)
+    sS, s1 = short(shards), short(1)
     match = all(
         np.array_equal(np.asarray(a), np.asarray(b))
-        for a, b in zip(jtu.tree_leaves(s8), jtu.tree_leaves(s1)))
-    row = {
+        for a, b in zip(jtu.tree_leaves(sS), jtu.tree_leaves(s1)))
+    return {
         "kind": "completed",
         "num_tiles": tiles,
         "devices": len(jax.devices()),
+        "platform": jax.devices()[0].platform,
         "quanta_per_s": sharded["quanta_per_s"],
         "quanta_per_s_single": single["quanta_per_s"],
-        "shard8_vs_single": round(
+        f"shard{shards}_vs_single": round(
             sharded["quanta_per_s"]
             / max(single["quanta_per_s"], 1e-9), 3),
         "sharded_matches_single": bool(match),
         "timed_quanta": sharded["timed_quanta"],
-        "workload": f"radix{tiles} shard8-vs-single A/B, "
+        "workload": f"radix{tiles} shard{shards}-vs-single A/B, "
                     f"{KEYS_PER_TILE} keys/tile",
     }
-    print("WEAK_SCALING_ROW " + json.dumps(row), flush=True)
 
 
 def _subprocess_cell(args, shards: int, timeout: float) -> dict:
@@ -283,16 +273,6 @@ def _subprocess_cell(args, shards: int, timeout: float) -> dict:
     return json.loads(rows[-1][len("WEAK_SCALING_ROW "):])
 
 
-def bench_shard8_row(tiles: int = 1024, quanta: int = QUANTA,
-                     warm: int = WARM, timeout: float = 3300.0) -> dict:
-    """Entry point bench.py imports: the A/B cell in a fresh 8-device
-    subprocess (the bench process itself does not force virtual
-    devices)."""
-    return _subprocess_cell(
-        ["--bench-shard8", "--tiles", str(tiles), "--quanta", str(quanta),
-         "--warm", str(warm)], 8, timeout)
-
-
 def _flag(argv, name, default):
     if name in argv:
         return argv[argv.index(name) + 1]
@@ -311,9 +291,6 @@ def main() -> int:
         i = argv.index("--leg-resident")
         run_leg(int(argv[i + 1]), int(argv[i + 2]), quanta, warm,
                 resident=True)
-        return 0
-    if "--bench-shard8" in argv:
-        run_bench_shard8(int(_flag(argv, "--tiles", 1024)), quanta, warm)
         return 0
 
     shards = [int(s) for s in
