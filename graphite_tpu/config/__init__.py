@@ -269,9 +269,8 @@ def load_config(
 def split_set_overrides(argv):
     """Partition ``argv`` into (positional_args, overrides) where the
     overrides are the ``sec/key=val`` payloads of ``--set sec/key=val``
-    or ``--set=sec/key=val`` flags — the shared flag grammar of the
-    profiling tools (tools/profile_round.py, tools/profile_phases.py),
-    extracted so the two parsers cannot drift."""
+    or ``--set=sec/key=val`` flags — the flag grammar of
+    tools/microbench_window.py."""
     overrides: List[str] = []
     plain: List[str] = []
     it = iter(argv)

@@ -51,6 +51,7 @@ from graphite_tpu.engine.state import (
 from graphite_tpu.engine.vparams import VariantParams, variant_params
 from graphite_tpu.events.schema import ICACHE_BYTES_PER_INSTRUCTION
 from graphite_tpu.isa import DVFSModule, EventOp, SyscallClass
+from graphite_tpu.obs import scopes
 from graphite_tpu import params as params_mod
 from graphite_tpu.params import SimParams
 
@@ -221,6 +222,7 @@ def _fast_forward_retire(params: SimParams, vp: VariantParams,
     )
 
 
+@jax.named_scope(scopes.FAST_FORWARD)
 def _fast_forward_guarded(params: SimParams, vp: VariantParams,
                           state: SimState,
                           trace: TraceArrays) -> SimState:
@@ -275,6 +277,7 @@ def _fast_forward_guarded(params: SimParams, vp: VariantParams,
                         floop, lambda s: s, state)
 
 
+@jax.named_scope(scopes.WINDOW_WALK)
 def _block_retire(params: SimParams, vp: VariantParams, st: SimState,
                   trace: TraceArrays, width: int = None,
                   tile_ids=None) -> SimState:
@@ -421,6 +424,7 @@ def _block_retire(params: SimParams, vp: VariantParams, st: SimState,
 
 # ======================================================== complex slot
 
+@jax.named_scope(scopes.COMPLEX_SLOT)
 def _complex_slot(params: SimParams, vp: VariantParams, state: SimState,
                   trace: TraceArrays) -> SimState:
     """One event per tile, every event kind — the general path."""
@@ -991,6 +995,7 @@ def _complex_slot(params: SimParams, vp: VariantParams, state: SimState,
     return st
 
 
+@jax.named_scope(scopes.COMPLEX_SLOT)
 def _complex_slot_guarded(params: SimParams, vp: VariantParams,
                           state: SimState,
                           trace: TraceArrays) -> SimState:

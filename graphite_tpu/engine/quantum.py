@@ -37,6 +37,7 @@ from graphite_tpu.engine.state import (
     PEND_JOIN, PEND_MUTEX, PEND_RECV, PEND_SEND, PEND_SH_REQ, PEND_START,
     SimState, TraceArrays, sampling_enabled, stats_ring_enabled)
 from graphite_tpu.engine.vparams import VariantParams, variant_params
+from graphite_tpu.obs import scopes
 from graphite_tpu.params import SimParams
 from graphite_tpu.time_base import TIME_MAX
 
@@ -129,6 +130,7 @@ def _tel_gauges(st: SimState) -> jnp.ndarray:
     ])
 
 
+@jax.named_scope(scopes.SAMPLE)
 def _maybe_sample(params: SimParams, state: SimState) -> SimState:
     """Record one statistics/progress sample when the quantum boundary
     crosses the sampling interval (the reference samples on barrier
@@ -188,6 +190,7 @@ def _maybe_sample(params: SimParams, state: SimState) -> SimState:
     return jax.lax.cond(do, take, lambda st: st, state)
 
 
+@jax.named_scope(scopes.SCHEDULE)
 def schedule_rotate(params: SimParams, state: SimState,
                     vp: VariantParams = None) -> SimState:
     """ThreadScheduler seat rotation (reference: thread_scheduler.h:30-56,
@@ -329,8 +332,9 @@ def quantum_step(params: SimParams, state: SimState,
     derives from ``params`` and traces as constants."""
     if vp is None:
         vp = variant_params(params)
-    state = state._replace(boundary=next_boundary(params, state, vp=vp),
-                           ctr_quantum=state.ctr_quantum + 1)
+    with jax.named_scope(scopes.BARRIER):
+        state = state._replace(boundary=next_boundary(params, state, vp=vp),
+                               ctr_quantum=state.ctr_quantum + 1)
     if state.sched_enabled:
         state = schedule_rotate(params, state, vp=vp)
 

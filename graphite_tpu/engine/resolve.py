@@ -41,6 +41,7 @@ from graphite_tpu.engine.state import (
     dword_tag, dword_with_meta)
 from graphite_tpu.engine.vparams import VariantParams, variant_params
 from graphite_tpu.isa import DVFSModule
+from graphite_tpu.obs import scopes
 from graphite_tpu.params import SimParams
 
 I, S, O, E, M = (cachemod.I, cachemod.S, cachemod.O, cachemod.E,
@@ -574,6 +575,7 @@ def chain_fast_pass(params: SimParams, vp: VariantParams, state: SimState,
     return state, ftbl
 
 
+@jax.named_scope(scopes.RESOLVE_MEMORY)
 def resolve_memory(params: SimParams, vp: VariantParams,
                    state: SimState) -> SimState:
     """Serve all parked L2-miss requests through the home directories.
@@ -2250,6 +2252,7 @@ def resolve(params: SimParams, state: SimState,
             any_mem, lambda x: resolve_memory(params, vp, x),
             lambda x: x, s)
 
+    @jax.named_scope(scopes.RESOLVE_SYNC)
     def sync_pass(s: SimState) -> SimState:
         if s.has_capi:
             # Traces with no CAPI traffic carry zero-size channel arrays

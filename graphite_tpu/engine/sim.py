@@ -410,9 +410,10 @@ class Simulator:
         # CAPI channel state is O(T^2); only allocate it when the trace
         # actually messages (scan once, host-side).
         from graphite_tpu.isa import EventOp
-        ops = np.asarray(trace.ops)
-        has_capi = bool(((ops == int(EventOp.SEND))
-                         | (ops == int(EventOp.RECV))).any())
+        with span("trace.scan"):
+            ops = np.asarray(trace.ops)
+            has_capi = bool(((ops == int(EventOp.SEND))
+                             | (ops == int(EventOp.RECV))).any())
         if has_capi and trace.num_tiles > params.num_tiles:
             raise ValueError(
                 "CAPI SEND/RECV with multi-thread-per-core scheduling is "
@@ -459,35 +460,40 @@ class Simulator:
                 break
             # The first window pays jit trace+compile (or cache load) on
             # top of device time; its span is named apart so compile
-            # cost is attributable in the exported host track.
+            # cost is attributable in the exported host track.  Inside
+            # it, sim.dispatch is the host enqueue (and that compile),
+            # sim.sync the host waiting on the device for the progress
+            # scalars; the rest is the driver's own bookkeeping.
             with span("sim.compile+window" if first_dispatch
                       else "sim.window", quanta=window * qps):
-                om_any = False
+                with span("sim.dispatch"):
+                    if self.ingest is not None:
+                        from graphite_tpu.engine import ingest as ingest_mod
+                        self.state, om = ingest_mod.megarun(
+                            self.params, self.state, self.trace,
+                            window * qps)
+                    elif self.params.shard_state == "resident":
+                        from graphite_tpu.engine import resident
+                        self.state = resident.megarun(
+                            self.params, self.state, self.trace,
+                            window * qps)
+                    else:
+                        self.state = megarun(self.params, self.state,
+                                             self.trace, window * qps)
                 if self.ingest is not None:
-                    from graphite_tpu.engine import ingest as ingest_mod
                     # Dispatch is async; the prefetch's host slice +
                     # upload below overlaps the device compute — that
                     # overlap IS the double buffer.
-                    self.state, om = ingest_mod.megarun(
-                        self.params, self.state, self.trace, window * qps)
                     self.ingest.start_prefetch()
+                with span("sim.sync"):
                     done, cursor_sum, clock_sum, quanta, om_any = \
                         jax.device_get(
                             (self.state.all_done(),
                              self.state.cursor.sum(),
                              self.state.clock.sum(),
-                             self.state.ctr_quantum, om.any()))
-                elif self.params.shard_state == "resident":
-                    from graphite_tpu.engine import resident
-                    self.state = resident.megarun(
-                        self.params, self.state, self.trace, window * qps)
-                else:
-                    self.state = megarun(self.params, self.state,
-                                         self.trace, window * qps)
-                if self.ingest is None:
-                    done, cursor_sum, clock_sum, quanta = jax.device_get(
-                        (self.state.all_done(), self.state.cursor.sum(),
-                         self.state.clock.sum(), self.state.ctr_quantum))
+                             self.state.ctr_quantum,
+                             om.any() if self.ingest is not None
+                             else False))
             first_dispatch = False
             if bool(om_any):
                 # Segment seam: the megarun stopped at a quantum

@@ -6,10 +6,18 @@ statistics_manager.cc:41-114, pin/progress_trace.cc, common/misc/log.h);
 engine/sim.py + engine/quantum.py carry those over.  This package adds
 observability of the *simulator itself*:
 
+  * ``scopes`` — the names of the quantum program's phases, each applied
+    as a ``jax.named_scope`` where the engine does that phase's work
+    (barrier, schedule, sample, window walk, complex slot, fast forward,
+    memory resolve, sync resolve).  Metadata only: a profiler trace of
+    the chip splits device time by phase through each op's ``op_name``.
   * ``spans`` — nestable host-side wall-clock span tracing for the driver
-    path (config resolution, trace load, jit compile, each polling-window
-    dispatch).  Near-zero overhead when disabled: one attribute check and
-    a shared no-op context manager.
+    path (config resolution, trace load/upload/scan, state alloc, each
+    polling window split into ``sim.dispatch`` and ``sim.sync``).  An
+    enabled span also writes a ``jax.profiler.TraceAnnotation``, so it
+    lands in a running profiler trace on the device's clock.  Near-zero
+    overhead when disabled: one attribute check and a shared no-op
+    context manager.
   * ``metrics`` — the device round-metric series sampled at quantum
     boundaries by engine/quantum._maybe_sample when [telemetry] is
     enabled (engine-health gauges: events retired, stall-reason

@@ -1,13 +1,16 @@
 """Host-side span tracing for the simulator's own driver path.
 
-The engine's device work is profiled by the round-metric gauges
-(obs/metrics.py); everything outside the jitted step — config
-resolution, trace load/annotation, jit compile, each polling-window
-dispatch — is wall-clock host work that used to require hand-rolled
-differencing (tools/profile_round.py) to attribute.  A ``SpanTracer``
-records nestable begin/end wall-clock events from ``with span(...)``
-context managers and renders them as Chrome trace-event ``X`` slices
-(obs/export.chrome_trace).
+The engine's device work is split by phase through ``jax.named_scope``
+(obs/scopes.py) and sampled by the round-metric gauges (obs/metrics.py);
+everything outside the jitted step — config resolution, trace load and
+upload, state allocation, jit compile, each polling window's dispatch
+(``sim.dispatch``) and progress read (``sim.sync``) — is wall-clock host
+work.  A ``SpanTracer`` records nestable begin/end wall-clock events from
+``with span(...)`` context managers and renders them as Chrome
+trace-event ``X`` slices (obs/export.chrome_trace).  An enabled span also
+opens a ``jax.profiler.TraceAnnotation`` of the same name, so under a
+running profiler it lands on the host plane of the profiler's trace, on
+the device trace's clock.
 
 Disabled-path cost: ``span()`` on a disabled tracer is one attribute
 check returning a shared no-op context manager — no allocation, no
@@ -18,6 +21,8 @@ from __future__ import annotations
 
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class SpanEvent(NamedTuple):
@@ -46,9 +51,11 @@ _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    """Context manager recording one span into its tracer on exit."""
+    """Context manager recording one span into its tracer on exit, and
+    into the profiler's trace (when one is running) as a
+    ``TraceAnnotation`` of the same name."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  args: Optional[Dict[str, Any]]):
@@ -57,6 +64,8 @@ class _LiveSpan:
         self._args = args
 
     def __enter__(self):
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._tracer._depth += 1
         self._t0 = time.perf_counter_ns()
         return self
@@ -68,6 +77,7 @@ class _LiveSpan:
         tr._record(SpanEvent(
             name=self._name, t0_ns=self._t0 - tr.epoch_ns,
             dur_ns=t1 - self._t0, depth=tr._depth, args=self._args))
+        self._annotation.__exit__(*exc)
         return False
 
 

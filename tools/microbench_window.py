@@ -21,7 +21,7 @@ round with kernels off vs on — the dispatch-chain the kernel absorbs.
 
 Usage: python tools/microbench_window.py [tiles] [iters] [--set sec/key=val ...]
 
-``--set`` forwards config overrides exactly like profile_round.py:
+``--set`` forwards config overrides (config.split_set_overrides):
 
     python tools/microbench_window.py 1024 20 --set tpu/block_events=4
     python tools/microbench_window.py 64 50 --set tpu/miss_chain=12
@@ -57,8 +57,8 @@ def _timed(fn, state, ta, iters):
 
 
 def fused(fn, state, ta, iters):
-    """Marginal per-iteration cost (differences out dispatch constants —
-    see profile_round.py)."""
+    """Marginal per-iteration cost: the difference of a 2x-iteration and
+    a 1x-iteration loop cancels the dispatch and sync constants."""
     t1 = _timed(fn, state, ta, iters)
     t2 = _timed(fn, state, ta, 2 * iters)
     return max(t2 - t1, 0.0) / iters * 1e6
