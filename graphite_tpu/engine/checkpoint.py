@@ -29,10 +29,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from graphite_tpu.engine.state import SimState, make_state
+from graphite_tpu.engine.state import SimState, make_state, \
+    sharers_from_planes
 from graphite_tpu.params import SimParams
 
-_SCHEMA_VERSION = 27  # v27: streaming segmented ingest (round 16) —
+_SCHEMA_VERSION = 28  # v28: the full-map sharer bitmap is stored as a
+#   flat, entry-major uint32 store (SimState.dir_sharers); a v26/v27
+#   [W*A, F] uint64 plane array converts on load (sharers_from_planes);
+#   v27: streaming segmented ingest (round 16) —
 #   streamed runs checkpoint at segment seams and record the ingest
 #   frame (__ingest_base / __ingest_segment_events / __ingest_n_total)
 #   beside the state leaves; state semantics are unchanged, so v26
@@ -146,9 +150,10 @@ def _open_checkpoint(path: str):
 
 
 # v27 added ingest metadata WITHOUT touching state-leaf semantics, so
-# v26 (whole-trace) checkpoints restore unchanged; anything older
+# v26 (whole-trace) checkpoints restore unchanged, and v28 only changed
+# the sharer store's layout, which converts on load; anything older
 # predates the routed-resolve counter semantics and is rejected.
-_COMPATIBLE_SCHEMAS = (26, 27)
+_COMPATIBLE_SCHEMAS = (26, 27, 28)
 
 
 def _check_schema(path: str, z) -> None:
@@ -161,6 +166,7 @@ def _check_schema(path: str, z) -> None:
 def _load_leaves(path: str, z, template: SimState) -> SimState:
     """Fill ``template``'s leaves from the archive, shape-verified."""
     arrays, treedef = _flatten_with_paths(template)
+    planes = int(z["__meta_schema"]) < 28
     leaves = []
     for key, tmpl in arrays.items():
         if key.startswith("__meta"):
@@ -176,6 +182,8 @@ def _load_leaves(path: str, z, template: SimState) -> SimState:
                 f"checkpoint {path!r} field {key!r} is unreadable "
                 f"({type(e).__name__}: {e}) — truncated or corrupt") \
                 from e
+        if planes and key == "dir_sharers":
+            a = sharers_from_planes(a, z["dir_word"].shape[-2])
         if a.shape != tmpl.shape:
             raise ValueError(
                 f"checkpoint field {key!r} shape {a.shape} != expected "

@@ -76,7 +76,8 @@ from graphite_tpu.engine.state import (
     PEND_BARRIER, PEND_CBC, PEND_COND, PEND_CSIG, PEND_EX_REQ, PEND_IFETCH,
     PEND_JOIN, PEND_MUTEX, PEND_NONE, PEND_RECV, PEND_SEND, PEND_SH_REQ,
     PEND_START, SimState, TraceArrays, dword_owner, dword_pack, dword_stamp,
-    dword_state, dword_tag, dword_with_meta)
+    dword_state, dword_tag, dword_with_meta, sharer_words, sharers_add,
+    sharers_gather)
 from graphite_tpu.engine.vparams import VariantParams, variant_params
 from graphite_tpu.isa import DVFSModule, EventOp
 from graphite_tpu.params import ConfigError, SimParams
@@ -224,8 +225,7 @@ def _vic_apply(params: SimParams, st: SimState, valid, g_tile, vline, vdirty,
     (same probe, same meta rewrites, same merged sharer-subtract scatter,
     with tile-BIT geometry in GLOBAL ids and set indices local)."""
     A = st.dir_word.shape[0]
-    W = st.dir_sharers.shape[0] // A
-    R = g_tile.shape[0]
+    W = sharer_words(params.num_tiles)
 
     drow = st.dir_word[:, fidx_l].T                       # [R, A]
     dstate = dword_state(drow)
@@ -237,10 +237,7 @@ def _vic_apply(params: SimParams, st: SimState, valid, g_tile, vline, vdirty,
     est = dword_state(word_way)
     eowner = dword_owner(word_way)
     # Sharer row of the matched way: [R, W].
-    sh_all = st.dir_sharers[:, fidx_l].reshape(W, A, R)
-    way_oh = (jnp.arange(A, dtype=jnp.int32)[None, :, None]
-              == way[None, None, :])
-    esh = jnp.sum(jnp.where(way_oh, sh_all, jnp.uint64(0)), axis=1).T  # [R, W]
+    esh = sharers_gather(st.dir_sharers, fidx_l, A, W, way)
     word_i = (g_tile // 64).astype(jnp.int32)
     bit = jnp.uint64(1) << (g_tile % 64).astype(jnp.uint64)
     woh = word_i[:, None] == jnp.arange(W, dtype=jnp.int32)[None, :]
@@ -259,16 +256,12 @@ def _vic_apply(params: SimParams, st: SimState, valid, g_tile, vline, vdirty,
     fmeta = jnp.where(mask_i | mask_s, fidx_l, _DROP)
     dir_word = st.dir_word.at[way, fmeta].set(new_word, mode="drop")
     # Merged sharer subtract: dead entries drop their whole row, live
-    # S/O entries drop this tile's bit (uint64 wraparound add).
-    plane = (jnp.arange(W, dtype=jnp.int32)[:, None] * A + way[None, :])
-    f_row = jnp.where(drop_m, fidx_l, _DROP)
-    f_bit = jnp.where((drop_s | drop_o) & has_bit, fidx_l, _DROP)
-    rows = jnp.concatenate([plane.reshape(-1), word_i * A + way])
-    cols = jnp.concatenate([
-        jnp.broadcast_to(f_row[None, :], (W, R)).reshape(-1), f_bit])
-    vals = jnp.concatenate([(jnp.uint64(0) - esh.T).reshape(-1),
-                            jnp.uint64(0) - bit])
-    dir_sharers = st.dir_sharers.at[rows, cols].add(vals, mode="drop")
+    # S/O entries drop this tile's bit (modular add).
+    dir_sharers = sharers_add(
+        st.dir_sharers, A, W,
+        rows=(drop_m, fidx_l, way, esh, jnp.zeros_like(esh)),
+        bits=((drop_s | drop_o) & has_bit, fidx_l, way, g_tile),
+        clear_bits=True)
     # Dirty victims write back at the home controller (home == dram site
     # for the private-L2 fold).
     wb = jnp.zeros((num_local,), jnp.int64).at[
@@ -293,7 +286,7 @@ def _routed_pass(params: SimParams, vp: VariantParams, st: SimState,
     TL = T // S
     P = params.miss_chain
     A = st.dir_word.shape[0]
-    W = st.dir_sharers.shape[0] // A
+    W = sharer_words(T)
     ndsets = st.dir_word.shape[1] // TL
     FL = TL * ndsets
     C = min(cap, 2 * TL)          # per-(source, dest) record capacity
@@ -397,8 +390,6 @@ def _routed_pass(params: SimParams, vp: VariantParams, st: SimState,
         h_extra = rec[:, 5]
 
         drow = st.dir_word[:, fidx_l].T                    # [R, A]
-        dsharers = st.dir_sharers[:, fidx_l].reshape(W, A, R) \
-            .transpose(2, 1, 0)                            # [R, A, W]
         dstate = dword_state(drow)
         dstamp = dword_stamp(drow)
         match = (dword_tag(drow) == rline[:, None]) & (dstate != _I)
@@ -423,8 +414,8 @@ def _routed_pass(params: SimParams, vp: VariantParams, st: SimState,
         way_word = jnp.take_along_axis(drow, way[:, None], axis=1)[:, 0]
         way_state = dword_state(way_word)
         way_owner = dword_owner(way_word)
-        entry_row = jnp.take_along_axis(
-            dsharers, way[:, None, None], axis=1)[:, 0, :]  # [R, W]
+        entry_row = sharers_gather(st.dir_sharers, fidx_l, A, W,
+                                   way)                    # [R, W]
         entry_state = jnp.where(hit, way_state, _I)
         entry_owner = jnp.where(hit, way_owner, -1)
         entry_sharers = jnp.where(hit[:, None], entry_row, jnp.uint64(0))
@@ -455,16 +446,13 @@ def _routed_pass(params: SimParams, vp: VariantParams, st: SimState,
             | (act.owner_leg & (posr >= J_OWN)))
 
         # Directory apply.
-        delta_sh = act.new_sharers - entry_row
         fidx_w = jnp.where(serve, fidx_l, _DROP)
         new_word = dword_pack(rline, st.round_ctr, act.new_state,
                               act.new_owner)
         dir_word = st.dir_word.at[way, fidx_w].set(new_word, mode="drop")
-        plane = (jnp.arange(W, dtype=jnp.int32)[:, None] * A + way[None, :])
-        dir_sharers = st.dir_sharers.at[
-            plane.reshape(-1),
-            jnp.broadcast_to(fidx_w[None, :], (W, R)).reshape(-1)].add(
-                delta_sh.T.reshape(-1), mode="drop")
+        dir_sharers = sharers_add(
+            st.dir_sharers, A, W,
+            rows=(serve, fidx_l, way, entry_row, act.new_sharers))
         st = st._replace(dir_word=dir_word, dir_sharers=dir_sharers)
 
         # Timing (chain.py's queue-off private-L2 legs; uniform periods
@@ -1158,9 +1146,7 @@ def modeled_step_bytes(params: SimParams, state: SimState) -> Dict[str, int]:
     TL = T // S
     C = route_capacity(params)
     R = S * C
-    A = params.directory.associativity
-    W = int(np.asarray(state.dir_sharers).shape[0]) // A \
-        if np.asarray(state.dir_sharers).size else 1
+    W = sharer_words(T)
     NP = max(3, 2 + W)
     cross = (S - 1) / S if S > 1 else 0.0
 

@@ -38,7 +38,7 @@ from graphite_tpu.engine.state import (
     PEND_BARRIER, PEND_CBC, PEND_COND, PEND_CSIG, PEND_EX_REQ, PEND_IFETCH,
     PEND_JOIN, PEND_MUTEX, PEND_NONE, PEND_RECV, PEND_SEND, PEND_SH_REQ,
     PEND_START, SimState, dword_owner, dword_pack, dword_stamp, dword_state,
-    dword_tag, dword_with_meta)
+    dword_tag, dword_with_meta, sharer_words, sharers_add, sharers_gather)
 from graphite_tpu.engine.vparams import VariantParams, variant_params
 from graphite_tpu.isa import DVFSModule
 from graphite_tpu.obs import scopes
@@ -204,7 +204,7 @@ def chain_fast_pass(params: SimParams, vp: VariantParams, state: SimState,
     P = params.miss_chain
     T = params.num_tiles
     A = params.directory.associativity
-    W = state.dir_sharers.shape[0] // A
+    W = sharer_words(T)
     ndsets = params.directory.num_sets
     rows = jnp.arange(T)
     shared_l2 = params.shared_l2
@@ -282,8 +282,7 @@ def chain_fast_pass(params: SimParams, vp: VariantParams, state: SimState,
 
         # ---- directory entry rows at (home, dset) — ONE gather each
         drow = state.dir_word[:, fidx].T                       # [T, A]
-        dsharers = state.dir_sharers[:, fidx].reshape(
-            W, A, T).transpose(2, 1, 0)                        # [T, A, W]
+        dsharers = sharers_gather(state.dir_sharers, fidx, A, W)  # [T, A, W]
 
         queue_on = params.dram.queue_model_enabled
         ci = kchain.ChainIn(
@@ -349,20 +348,15 @@ def chain_fast_pass(params: SimParams, vp: VariantParams, state: SimState,
             way, fidx_w].set(
             dword_pack(line, state.round_ctr, co.new_state,
                        co.new_owner), mode="drop"))
-        # Reps land (new - old) per plane; combining members add their
-        # own bit on top of the rep's rewritten row — ONE merged
+        # Reps rewrite their row (old + delta); combining members add
+        # their own bit on top of the rep's rewritten row — ONE merged
         # scatter-add, as in the round loop.
-        plane = jnp.arange(W, dtype=jnp.int32)[:, None] * A + way[None, :]
-        req_word = (rows // 64).astype(jnp.int32)
-        req_bit = jnp.uint64(1) << (rows % 64).astype(jnp.uint64)
-        add_rows = jnp.concatenate(
-            [plane.reshape(-1), req_word * A + way])
-        add_cols = jnp.concatenate(
-            [jnp.broadcast_to(fidx_w[None, :], (W, T)).reshape(-1),
-             jnp.where(co.member_add, fidx, jnp.int32(2**30))])
-        add_vals = jnp.concatenate([co.delta_sh.T.reshape(-1), req_bit])
-        state = state._replace(dir_sharers=state.dir_sharers.at[
-            add_rows, add_cols].add(add_vals, mode="drop"))
+        old_row = jnp.take_along_axis(
+            dsharers, way[:, None, None], axis=1)[:, 0, :]
+        state = state._replace(dir_sharers=sharers_add(
+            state.dir_sharers, A, W,
+            rows=(serve, fidx, way, old_row, old_row + co.delta_sh),
+            bits=(co.member_add, fidx, way, rows)))
 
         # ---- owner-side downgrade deliveries: per-target [T, J_OWN]
         # line lists (ranks < J_OWN are unique per target by the budget
@@ -593,7 +587,7 @@ def resolve_memory(params: SimParams, vp: VariantParams,
     """
     T = params.num_tiles
     A = params.directory.associativity
-    W = state.dir_sharers.shape[0] // A
+    W = sharer_words(T)
     K = min(params.max_inv_fanout_per_round, T)
     # Election hash-table size: keys are fmix64-mixed, so collisions are
     # birthday-random — with up to T concurrent keys the expected number
@@ -793,15 +787,12 @@ def resolve_memory(params: SimParams, vp: VariantParams,
         way_owner = dword_owner(way_word)
         evicting = misswin & (way_state != I)
 
-        dsharers = state.dir_sharers[:, fidx].reshape(
-            W, A, T).transpose(2, 1, 0)                       # [T, A, W]
+        way_sharers = sharers_gather(state.dir_sharers, fidx, A, W,
+                                     way)                     # [T, W]
         entry_state = jnp.where(hit, way_state, I)
         entry_owner = jnp.where(hit, way_owner, -1)
-        entry_sharers = jnp.where(
-            hit[:, None],
-            jnp.take_along_axis(
-                dsharers, way[:, None, None], axis=1)[:, 0, :],
-            jnp.zeros((T, W), dtype=jnp.uint64))
+        entry_sharers = jnp.where(hit[:, None], way_sharers,
+                                  jnp.zeros((T, W), dtype=jnp.uint64))
 
         # Victim directory entry being replaced (reference invalidates all
         # of the victim's sharers/owner on directory-cache replacement —
@@ -810,8 +801,7 @@ def resolve_memory(params: SimParams, vp: VariantParams,
         vtag = dword_tag(way_word).astype(jnp.int64)
         vstate = jnp.where(evicting, way_state, I)
         vowner = way_owner
-        vsharers = jnp.take_along_axis(
-            dsharers, way[:, None, None], axis=1)[:, 0, :]
+        vsharers = way_sharers
         # Owner-flush victims: M always; E too under shared-L2 MESI (the
         # exclusive owner may have silently upgraded, so its flush is
         # conservatively priced and written back like a dirty one).
@@ -1238,28 +1228,17 @@ def resolve_memory(params: SimParams, vp: VariantParams,
             dir_word=state.dir_word.at[way, fidx_w].set(
                 dword_pack(line, state.round_ctr, act.new_state,
                            act.new_owner), mode="drop"))
-        # Sharer-bitmap rewrite as per-PLANE modular delta-adds: the slot's
-        # current row is known (the hit entry's words, or the victim's for
-        # a fresh alloc), so adding (new - old) lands the new row exactly —
-        # one [T]-row single-word scatter per plane, where a multi-word
-        # row .set was a W*T-row (or strided-window) scatter that XLA:TPU
-        # serialized at ~30 ms per conflict round at 1024 tiles.
-        # ONE merged scatter-add: every scatter into dir_sharers costs a
-        # full-array sweep on TPU (the lowering loops over major lines),
-        # so the W per-plane rep deltas and the combined winners' bit adds
-        # ride the same op.
+        # Sharer-bitmap rewrite as modular delta-adds: the slot's current
+        # row is known (the hit entry's words, or the victim's for a fresh
+        # alloc), so adding (new - old) per u32 word lands the new row
+        # exactly, and the combined winners' bits ride the same
+        # scatter-add — one 1-D scatter into the flat store, so no
+        # relayout of it (see SimState.dir_sharers).
         old_row = jnp.where(hit[:, None], entry_sharers, vsharers)
-        delta = act.new_sharers - old_row          # uint64, modular
-        fidx_rep = jnp.where(win & ~combinable, fidx, jnp.int32(2**30))
-        plane = jnp.arange(W, dtype=jnp.int32)[:, None] * A + way[None, :]
-        add_rows = jnp.concatenate(
-            [plane.reshape(-1), req_word * A + way])
-        add_cols = jnp.concatenate(
-            [jnp.broadcast_to(fidx_rep[None, :], (W, T)).reshape(-1),
-             jnp.where(sharer_add, fidx, jnp.int32(2**30))])
-        add_vals = jnp.concatenate([delta.T.reshape(-1), req_bit1])
-        state = state._replace(dir_sharers=state.dir_sharers.at[
-            add_rows, add_cols].add(add_vals, mode="drop"))
+        state = state._replace(dir_sharers=sharers_add(
+            state.dir_sharers, A, W,
+            rows=(win & ~combinable, fidx, way, old_row, act.new_sharers),
+            bits=(sharer_add, fidx, way, rows)))
 
         # ---- coherence-driven cache-state changes, one single-pass sweep
         # per cache level over per-target line lists: owner downgrades
@@ -1657,8 +1636,8 @@ class _VictimProbe:
                  valid):
         T = params.num_tiles
         A = params.directory.associativity
-        W = state.dir_sharers.shape[0] // A
-        self.assoc = A
+        W = sharer_words(T)
+        self.assoc, self.words, self.tiles = A, W, tiles
         ndsets = params.directory.num_sets
         self.vhome = home_of_line(params, vtag)
         self.vdset = dir_set_of_line(params, vtag)
@@ -1674,11 +1653,8 @@ class _VictimProbe:
             drow, self.way[:, None], axis=1)[:, 0]
         self.est = dword_state(self.word_way)
         self.eowner = dword_owner(self.word_way)
-        self.esharers = jnp.sum(
-            jnp.where((jnp.arange(A, dtype=jnp.int32)[:, None]
-                       == self.way[None, :])[None, :, :],
-                      state.dir_sharers[:, vfidx].reshape(W, A, -1),
-                      jnp.uint64(0)), axis=1, dtype=jnp.uint64).T  # [T, W]
+        self.esharers = sharers_gather(state.dir_sharers, vfidx, A, W,
+                                       self.way)              # [T, W]
         self.word = (tiles // 64).astype(jnp.int32)
         self.bit = jnp.uint64(1) << (tiles % 64).astype(jnp.uint64)
         self.woh = self.word[:, None] \
@@ -1714,12 +1690,10 @@ class _VictimProbe:
         """Clear the dropping tile's sharer bit where ``mask`` (guarded
         commutative subtract — distinct sharers of one entry may clear in
         the same batch)."""
-        f = jnp.where(mask & self.has_bit, self.vfidx,
-                      jnp.int32(2**30))
-        return state._replace(
-            dir_sharers=state.dir_sharers.at[
-                self.word * self.assoc + self.way, f].add(
-                jnp.uint64(0) - self.bit, mode="drop"))
+        return state._replace(dir_sharers=sharers_add(
+            state.dir_sharers, self.assoc, self.words,
+            bits=(mask & self.has_bit, self.vfidx, self.way, self.tiles),
+            clear_bits=True))
 
 
 def _dir_evict_notify(params: SimParams, state: SimState, tiles, vtag,
@@ -1733,9 +1707,6 @@ def _dir_evict_notify(params: SimParams, state: SimState, tiles, vtag,
     different sharers of the same line all land.  (Reference: eviction
     writeback messages into dram_directory_cntlr.)
     """
-    T = params.num_tiles
-    A = params.directory.associativity
-    W = state.dir_sharers.shape[0] // A
     p = _VictimProbe(params, state, tiles, vtag, valid)
 
     # Owner dropped its M line: entry -> I.
@@ -1756,23 +1727,14 @@ def _dir_evict_notify(params: SimParams, state: SimState, tiles, vtag,
                         drop_m | ((drop_s | drop_o) & empty), I, -1,
                         drop_o & ~empty, S, -1)
     # M drop wipes the whole bitmap row (the owner was the only holder) by
-    # modular subtract of the known contents; S/O drops clear one bit.
-    # Merged into ONE scatter-add — each dir_sharers scatter sweeps the
-    # whole array on TPU (see the winner write in resolve_memory).
-    fm = jnp.where(drop_m, p.vfidx, jnp.int32(2**30))
-    clr = drop_s | drop_o
-    fc = jnp.where(clr & p.has_bit, p.vfidx, jnp.int32(2**30))
-    R = tiles.shape[0]          # == T from the round loop, P*T vectorized
-    plane = jnp.arange(W, dtype=jnp.int32)[:, None] * A + p.way[None, :]
-    rows2 = jnp.concatenate(
-        [plane.reshape(-1), p.word * A + p.way])
-    cols2 = jnp.concatenate(
-        [jnp.broadcast_to(fm[None, :], (W, R)).reshape(-1), fc])
-    vals2 = jnp.concatenate(
-        [(jnp.uint64(0) - p.esharers.T).reshape(-1),
-         jnp.uint64(0) - p.bit])
-    return state._replace(dir_sharers=state.dir_sharers.at[
-        rows2, cols2].add(vals2, mode="drop"))
+    # modular subtract of the known contents; S/O drops clear one bit —
+    # one scatter-add.
+    return state._replace(dir_sharers=sharers_add(
+        state.dir_sharers, p.assoc, p.words,
+        rows=(drop_m, p.vfidx, p.way, p.esharers,
+              jnp.zeros_like(p.esharers)),
+        bits=((drop_s | drop_o) & p.has_bit, p.vfidx, p.way, tiles),
+        clear_bits=True))
 
 
 def _sh_l1_evict_notify(params: SimParams, state: SimState, tiles, vtag,

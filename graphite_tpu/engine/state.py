@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -322,12 +323,18 @@ class SimState(NamedTuple):
     # copy per conflict round (profiled at ~4.5 ms per round on the 512 MB
     # 1024-tile sharer bitmap).
     dir_word: jnp.ndarray     # [dassoc, T*dsets] int64 packed entries
-    dir_sharers: jnp.ndarray  # [W*dassoc, T*dsets] uint64 sharer bitmaps —
-    #   plane (w, way) lives at row w*dassoc + way.  Two-dimensional so
-    #   every sharer update is a (row, col)-indexed single-word scatter;
-    #   3-D layouts made XLA:TPU serialize the scatters into
-    #   per-(plane, way) dynamic-update-slice loops (~30 ms/round at
-    #   1024 tiles).  See dir_sharers_view for the unpacked view.
+    dir_sharers: jnp.ndarray  # [T*dsets * dassoc * 2W] uint32 full-map
+    #   sharer bitmaps, flat and entry-major: word j of entry (fidx, way)
+    #   lives at (fidx*dassoc + way)*2W + j and holds bitmap bits
+    #   32j..32j+31 (W = sharer_words(T) u64 words per row).  Flat, because
+    #   XLA:TPU lowers a two-index scatter into any 2-D layout as a scatter
+    #   into a flat buffer plus a relayout of the WHOLE array back into the
+    #   tiled layout (2 GiB per conflict round at 1024 tiles); u32, because
+    #   an int64 array is split into two u32 halves with a whole-array
+    #   temporary.  Entry-major, so an entry's rows are one contiguous
+    #   block and an even split of the flat axis is an even split over
+    #   tiles.  Read and written only through sharers_gather/sharers_add;
+    #   dir_sharers_view gives the [A, F, W] uint64 view.
 
     # -- banked miss chains (tpu/miss_chain > 0; engine/core.py window).
     # BLOCKING semantics (round 7): the block window executes past L2
@@ -556,12 +563,96 @@ class SimState(NamedTuple):
         return dword_stamp(self.dir_word)
 
 
+def sharer_words(num_tiles: int) -> int:
+    """uint64 words per full-map sharer row."""
+    return (num_tiles + 63) // 64
+
+
+def _join_words(halves: jnp.ndarray) -> jnp.ndarray:
+    """[..., 2W] uint32 (lo, hi interleaved) -> [..., W] uint64."""
+    pairs = halves.reshape(halves.shape[:-1] + (-1, 2)).astype(jnp.uint64)
+    return pairs[..., 0] | (pairs[..., 1] << jnp.uint64(32))
+
+
+def _split_words(rows: jnp.ndarray) -> jnp.ndarray:
+    """[..., W] uint64 -> [..., 2W] uint32 (lo, hi interleaved)."""
+    halves = jnp.stack([rows.astype(jnp.uint32),
+                        (rows >> jnp.uint64(32)).astype(jnp.uint32)], -1)
+    return halves.reshape(rows.shape[:-1] + (-1,))
+
+
+def sharers_gather(sh: jnp.ndarray, fidx: jnp.ndarray, assoc: int,
+                   words: int, way: Optional[jnp.ndarray] = None
+                   ) -> jnp.ndarray:
+    """[N] flat entry ids -> [N, assoc, words] uint64 sharer rows, or
+    with ``way`` ([N]) the [N, words] row of that way alone.
+
+    All ways are one contiguous slice per entry; one way is an element
+    gather of its 2*words words, which XLA:TPU runs as one gather op
+    (the slice gather is a loop over entries: 20x slower per call at 64
+    tiles, 1.8x at 1024, on a v5e)."""
+    w32 = 2 * words
+    fidx = fidx.astype(jnp.int32)
+    if way is None:
+        n = assoc * w32
+        block = jax.vmap(lambda f: jax.lax.dynamic_slice(sh, (f * n,), (n,)))(
+            fidx)
+        return _join_words(block.reshape(fidx.shape[0], assoc, w32))
+    base = (fidx * assoc + way.astype(jnp.int32)) * w32
+    return _join_words(sh[base[:, None] + jnp.arange(w32, dtype=jnp.int32)])
+
+
+def sharers_add(sh: jnp.ndarray, assoc: int, words: int, *, rows=None,
+                bits=None, clear_bits: bool = False) -> jnp.ndarray:
+    """Every sharer update of one step as ONE uint32 scatter-add.
+
+    ``rows = (mask, fidx, way, old, new)`` rewrites the row of entry
+    (fidx, way) from ``old`` to ``new`` ([N, words] uint64) where
+    ``mask``: each u32 word adds its own modular ``new - old`` (a uint64
+    delta split in halves would be wrong wherever the low half borrows).
+    ``bits = (mask, fidx, way, tile)`` adds tile's bit to the row, or
+    subtracts it with ``clear_bits`` (the bit must then be set, and for
+    an add clear).  Masked-off updates index one past the end and
+    drop."""
+    w32 = 2 * words
+    end = sh.shape[0]
+
+    def base(fidx, way):
+        return (fidx.astype(jnp.int32) * assoc + way.astype(jnp.int32)) * w32
+
+    idx, vals = [], []
+    if rows is not None:
+        mask, fidx, way, old, new = rows
+        i = base(fidx, way)[:, None] + jnp.arange(w32, dtype=jnp.int32)
+        idx.append(jnp.where(mask[:, None], i, end).reshape(-1))
+        vals.append((_split_words(new) - _split_words(old)).reshape(-1))
+    if bits is not None:
+        mask, fidx, way, tile = bits
+        tile = tile.astype(jnp.int32)
+        idx.append(jnp.where(mask, base(fidx, way) + tile // 32, end))
+        bit = jnp.uint32(1) << (tile % 32).astype(jnp.uint32)
+        vals.append(jnp.uint32(0) - bit if clear_bits else bit)
+    return sh.at[jnp.concatenate(idx)].add(jnp.concatenate(vals),
+                                           mode="drop")
+
+
 def dir_sharers_view(state: "SimState", assoc: int) -> jnp.ndarray:
-    """[W*A, F] flat sharer planes -> [A, F, W] word-minor view (for tests
-    and tools; the engine itself works on the flat planes)."""
-    WA, F = state.dir_sharers.shape
-    W = WA // assoc
-    return jnp.moveaxis(state.dir_sharers.reshape(W, assoc, F), 0, -1)
+    """Flat sharer store -> [A, F, W] uint64 word-minor view (for tests
+    and tools; the engine reads rows through sharers_gather)."""
+    F = state.dir_word.shape[-1]
+    rows = _join_words(state.dir_sharers.reshape(F, assoc, -1))
+    return jnp.moveaxis(rows, 1, 0)
+
+
+def sharers_from_planes(planes: np.ndarray, assoc: int) -> np.ndarray:
+    """The [..., W*A, F] uint64 plane layout of checkpoint schemas <= 27
+    -> the flat [..., F*A*2W] uint32 store (leading axes kept)."""
+    *lead, wa, f = planes.shape
+    rows = np.moveaxis(planes.reshape(*lead, wa // assoc, assoc, f),
+                       (-3, -1), (-1, -3))               # [..., F, A, W]
+    halves = np.stack([rows & np.uint64(0xFFFFFFFF), rows >> np.uint64(32)],
+                      axis=-1).astype(np.uint32)
+    return halves.reshape(*lead, -1)
 
 
 def init_periods(params: SimParams) -> np.ndarray:
@@ -654,7 +745,14 @@ def make_state(params: SimParams,
         channel_depth = params.channel_depth
     d_shape = (params.directory.associativity,
                T * params.directory.num_sets)
-    W = (T + 63) // 64  # sharer bitmap words (full_map)
+    n_sharer = d_shape[1] * d_shape[0] * 2 * sharer_words(T)
+    per_device = n_sharer // (params.tile_shards
+                              if params.shard_state == "resident" else 1)
+    if per_device >= 2**31:
+        raise ValueError(
+            f"the full-map sharer store needs {per_device} uint32 words per "
+            f"device, past the int32 index range (2**31); use fewer "
+            f"directory sets or ways, or more resident tile shards")
     return SimState(
         clock=jnp.zeros(T, dtype=jnp.int64),
         cursor=jnp.zeros(T, dtype=jnp.int32),
@@ -680,8 +778,7 @@ def make_state(params: SimParams,
         period_ps=jnp.asarray(init_periods(params)),
         # I-state, owner -1, tag/stamp 0 packs to the all-zeros word.
         dir_word=jnp.zeros(d_shape, dtype=jnp.int64),
-        dir_sharers=jnp.zeros((W * d_shape[0], d_shape[1]),
-                              dtype=jnp.uint64),
+        dir_sharers=jnp.zeros(n_sharer, dtype=jnp.uint32),
         mq_req=jnp.zeros((params.miss_chain, T), dtype=jnp.int64),
         mq_delta=jnp.zeros((params.miss_chain, T), dtype=jnp.int64),
         mq_extra=jnp.zeros((params.miss_chain, T), dtype=jnp.int64),

@@ -116,7 +116,7 @@ _TILE_AXIS_BY_FIELD = {
     #   chain_fallback counters are tile-leading, covered by the
     #   default axis-0 rule below)
     "dir_word": 1,                   # [A, T*dsets] (tile-major flat)
-    "dir_sharers": 1,                # [W*A, T*dsets]
+    "dir_sharers": 0,                # [T*dsets*A*2W] u32 (entry-major flat)
     "ch_time": 1,                    # [D, T, T]
     "mq_req": 1,                     # [P, T] banked miss chains
     "mq_delta": 1, "mq_extra": 1,    # (blocking chain replay, round 7)

@@ -348,3 +348,45 @@ def test_resident_old_schema_rejected(tmp_path):
 
     with pytest.raises(ValueError, match="schema 25"):
         Simulator(params, trace).restore_checkpoint(old)
+
+
+def test_v27_sharer_planes_restore_identical(tmp_path):
+    """Schema 28 stores the sharer bitmap as flat uint32 words; a v27
+    checkpoint's [W*A, F] uint64 planes (plane (w, way) at row w*A + way)
+    convert on load, bit for bit, and the resumed run equals the
+    unbroken one."""
+    cfg = load_config()
+    cfg.set("general/total_cores", 8)
+    params = SimParams.from_config(cfg)
+    trace = synth.gen_radix(num_tiles=8, keys_per_tile=16, radix=16, seed=3)
+
+    full = Simulator(params, trace)
+    s_full = full.run()
+
+    half = Simulator(params, trace)
+    half.run(max_steps=2)
+    ck = str(tmp_path / "v28.npz")
+    half.save_checkpoint(ck)
+    with np.load(ck) as z:
+        doctored = {k: z[k] for k in z.files}
+    flat = doctored["dir_sharers"]
+    assert flat.dtype == np.uint32 and flat.any()
+    A = params.directory.associativity
+    F = doctored["dir_word"].shape[1]
+    words = flat.reshape(F, A, -1, 2).astype(np.uint64)
+    rows = words[..., 0] | (words[..., 1] << np.uint64(32))     # [F, A, W]
+    doctored["dir_sharers"] = np.ascontiguousarray(
+        rows.transpose(2, 1, 0).reshape(-1, F))
+    doctored["__meta_schema"] = np.int64(27)
+    old = str(tmp_path / "v27.npz")
+    with open(old, "wb") as f:
+        np.savez(f, **doctored)
+
+    resumed = Simulator(params, trace)
+    resumed.restore_checkpoint(old)
+    np.testing.assert_array_equal(np.asarray(resumed.state.dir_sharers),
+                                  flat)
+    s_res = resumed.run()
+    assert s_full.completion_time_ps == s_res.completion_time_ps
+    for f, a in s_full.counters.items():
+        assert np.array_equal(a, s_res.counters[f]), f

@@ -11,6 +11,7 @@ here)."""
 
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
-from graphite_tpu.engine import quantum, resident
+from graphite_tpu.engine import quantum, resident, resolve
 from graphite_tpu.engine.state import TraceArrays, make_state
 from graphite_tpu.events import synth
 from graphite_tpu.parallel import mesh as meshmod
@@ -71,6 +72,47 @@ def test_megastep_t64_compiles_for_one_chip(topo):
         state, tarrays).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2**30        # one v5e's HBM
+
+
+_HLO_RESULT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]"
+                         r"\{([^}]*)\} (dynamic-update-slice|copy)\(")
+
+
+def _relayouts(hlo, sizes):
+    """Names of the dynamic-update-slice ops, and of the copies that
+    transpose a 2-D layout ({0,1:...}), whose result has an element
+    count in ``sizes``."""
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m:
+            continue
+        name, dims, layout, op = m.groups()
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        if n in sizes and (op == "dynamic-update-slice"
+                           or layout.startswith("0,1:")):
+            found.append(name)
+    return found
+
+
+def test_resolve_keeps_sharer_store_layout(topo):
+    """Memory resolve reads and writes the flat uint32 sharer store in
+    place: no dynamic-update-slice and no transposing copy of an array of
+    its size, in u32 words or in the u64 words of the 2-D plane layout
+    it replaced (whose relayout took 72% of a 1,024-tile round).  At 128
+    tiles, where those counts differ from dir_word's; at 64 they match."""
+    from graphite_tpu.engine.vparams import variant_params
+
+    one = SingleDeviceSharding(topo.devices[0])
+    params = workload_params(128, {})
+    st = make_state(params, has_capi=False)
+    n = st.dir_sharers.size
+    vp = variant_params(params)
+    hlo = jax.jit(lambda s: resolve.resolve_memory(params, vp, s)).lower(
+        _shapes(st, lambda *_: one)).compile().as_text()
+    assert _relayouts(hlo, {n, n // 2}) == []
 
 
 def test_resident_quantum_compiles_on_four_chips(topo):
