@@ -1,12 +1,42 @@
 """The benchmark harness: one cell of ``BENCHMARK.json``, from its data files.
 
-A cell names a configuration (``benchmarks/configs/<name>.json``: the
-graphite config keys the program runs with, and the model numbers the
-plain reference runs with) and a traffic mix
-(``benchmarks/traffic/<name>.json``: a generator of ``traffic.py`` and its
-parameters, with overrides per tile count, and the window mode).  Per-layer
-metrics are read by ``benchmarks/metrics/<name>.py``.  Nothing here names
-a cell, a configuration or a metric: a later PR adds those as files.
+A cell names a configuration and a traffic mix.  Nothing here names a
+cell, a configuration, a generator, a reference or a metric: a later PR
+adds each as new files, found by the name in the data, and edits none:
+
+  configuration  ``benchmarks/configs/<name>.json`` (the file its
+                 ``BENCHMARK.json`` entry names): ``sets``, the graphite
+                 config keys the program runs with; ``model``, the
+                 numbers the plain reference runs with; and optionally
+                 ``"reference": "<ref>"``.
+  reference      ``benchmarks/references/<ref>.py``, only where the
+                 configuration names one: exposes ``ReferenceSim(model,
+                 events)`` with ``run(stop_quanta=None) -> digest`` as
+                 ``harness/reference.py``'s, which it may ``import
+                 reference`` to subclass.  With no ``reference`` key the
+                 configuration is compared against ``harness/
+                 reference.py``.  A named module that is missing or has
+                 no ``ReferenceSim`` is a ``HarnessError``, never a
+                 fallback.
+  traffic        ``benchmarks/traffic/<name>.json``: a ``generator``, its
+                 ``params``, overrides per tile count (``scale``), and the
+                 window mode.
+  generator      ``benchmarks/generators/<generator>.py``, where the
+                 traffic names one that ``traffic.py`` does not build in:
+                 exposes ``generate(tiles, seed, params) -> {"ops",
+                 "addr", "arg", "arg2"}`` (``traffic.generate``).
+  metric         ``benchmarks/metrics/<name>.py`` per per-layer metric:
+                 exposes ``read(ctx)``, returning a number or ``None``
+                 when the run has nothing for it to read.
+
+A traced run's ``ctx``: ``busy_s`` and ``window_s`` (device trace),
+``rounds`` and ``icount`` (the window's ``round_ctr`` and instructions),
+``counters`` (every per-tile counter summed over tiles and over the
+window's simulations, the cut one included), ``round_ctrs`` (the same
+sums of ``ROUND_FIELDS``), ``phase_s`` (device seconds per engine phase,
+``phases.reduce``; empty with no device plane), ``span_s`` (the
+durations of the program's spans that end in the window), ``sim_init_s``,
+``compile_s`` and ``mode``.
 
 A run: set-up (imports, trace from ``--seed``, program build, compile or
 cache load, warm-up), then the measured window, then the memory reading,
@@ -23,7 +53,6 @@ Window modes:
 
 from __future__ import annotations
 
-import importlib.util
 import json
 import os
 import shutil
@@ -33,11 +62,16 @@ import time
 
 import numpy as np
 
+import plugins
+import traffic
+
 HARNESS = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HARNESS)
 ROOT = os.path.dirname(BENCH)
 OUT = os.path.join(ROOT, ".bench_out")        # traces; listed in .gitignore
 TRACE_DIR = os.path.join(OUT, "trace")
+REFERENCE_DIR = os.path.join(BENCH, "references")
+METRIC_DIR = os.path.join(BENCH, "metrics")
 TRACE_SECONDS = 3           # the traced window, at most
 MB = 1e6
 
@@ -95,21 +129,12 @@ def reference_model(config):
     return model
 
 
-def _traffic_module():
-    if HARNESS not in sys.path:
-        sys.path.insert(0, HARNESS)
-    import traffic
-    return traffic
-
-
 def window_mode(c):
-    return _traffic_module().resolve(c["traffic"],
-                                     tiles_of(c["config"]))["window"]
+    return traffic.resolve(c["traffic"], tiles_of(c["config"]))["window"]
 
 
 def make_events(c, seed):
-    return _traffic_module().generate(c["traffic"], tiles_of(c["config"]),
-                                      seed)
+    return traffic.generate(c["traffic"], tiles_of(c["config"]), seed)
 
 
 # ------------------------------------------------------------ program side
@@ -278,19 +303,32 @@ def compare(prog, ref):
 LIMITS = {"values_differing": 0, "max_clock_gap_ps": 0}
 
 
-def check(sims, model, events):
-    """Run the plain reference over the window's simulations: complete ones
-    against its completed run, a cut one against its state at the quantum
-    the cut one stopped at.  Returns (failed, compared numbers)."""
-    if HARNESS not in sys.path:
-        sys.path.insert(0, HARNESS)
-    import reference
+def reference_class(config):
+    """The plain reference a configuration is compared against: the
+    ``ReferenceSim`` of ``REFERENCE_DIR/<name>.py`` where its
+    ``reference`` names one, else ``harness/reference.py``'s."""
+    name = config.get("reference")
+    if name is None:
+        import reference
+        return reference.ReferenceSim
+    mod = plugins.load(REFERENCE_DIR, name, "reference", HarnessError)
+    if not hasattr(mod, "ReferenceSim"):
+        raise HarnessError(f"{plugins.path_of(REFERENCE_DIR, name)} has no "
+                           f"ReferenceSim")
+    return mod.ReferenceSim
+
+
+def check(sims, model, events, ref_cls):
+    """Run the plain reference ``ref_cls`` over the window's simulations:
+    complete ones against its completed run, a cut one against its state
+    at the quantum the cut one stopped at.  Returns (failed, compared
+    numbers)."""
     worst = {k: 0 for k in LIMITS}
     failed, n = 0, 0
     ok_sims = [s for s in sims if "error" not in s]
     failed += len(sims) - len(ok_sims)
     if ok_sims:
-        ref = reference.ReferenceSim(model, events)
+        ref = ref_cls(model, events)
         cuts = sorted({s["ctr_quantum"] for s in ok_sims
                        if not s["all_done"]})
         snaps = {q: ref.run(stop_quanta=q) for q in cuts}
@@ -311,19 +349,20 @@ def check(sims, model, events):
 # ------------------------------------------------------------ metrics
 
 def load_reader(name):
-    path = os.path.join(BENCH, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return plugins.load(METRIC_DIR, name, "metric", HarnessError).read
 
 
-def window_counts(sims):
-    """Instructions and engine rounds the window's simulations retired."""
+def window_totals(sims):
+    """Every per-tile counter and every round counter, summed over tiles
+    and over the window's simulations (the cut one included)."""
     ok = [s for s in sims if "error" not in s]
-    return (sum(int(s["counters"]["icount"].sum()) for s in ok),
-            sum(s["round_ctrs"]["round_ctr"] for s in ok))
+    counters, rounds = {}, dict.fromkeys(ROUND_FIELDS, 0)
+    for s in ok:
+        for k, v in s["counters"].items():
+            counters[k] = counters.get(k, 0) + int(np.asarray(v).sum())
+        for f in ROUND_FIELDS:
+            rounds[f] += int(s["round_ctrs"][f])
+    return counters, rounds
 
 
 def device_line():
@@ -355,6 +394,22 @@ def _instrument_dispatch():
     def undo():
         simmod.megarun = orig
     return undo
+
+
+def program_op_names(params, trace, sim):
+    """Traced runs: instruction name -> ``op_name`` of the executable the
+    window runs, from its HLO text (``phases.op_names_from_hlo``).  The
+    compile finds the window's program in jax's caches; it counts as
+    set-up, not as ``compile_s``."""
+    import phases
+    from graphite_tpu.engine import quantum
+    from graphite_tpu.engine.sim import Simulator
+    s = sim if sim is not None else Simulator(params, trace)
+    fn = quantum._megarun_donate \
+        if params.tile_shards <= 1 and quantum.state_donation_enabled() \
+        else quantum._megarun_nodonate
+    text = fn.lower(params, s.state, s.trace, 0).compile().as_text()
+    return phases.op_names_from_hlo(text)
 
 
 def control_cell(c, miss_chain=8):
@@ -389,17 +444,22 @@ def measure_program(c, seed, seconds, traced, t_main, check_chip=True):
         config = dict(config, sets={**config["sets"], **c["program_sets"]})
     params, trace, sim = setup_program(dict(c, config=config), events, mode)
     compile_setup = clock.seconds
-    n_compiles = clock.count
-    window = min(seconds, TRACE_SECONDS) if traced else seconds
-    undo = None
+    window, undo = seconds, None
     if traced:
+        from graphite_tpu import obs
+        window = min(seconds, TRACE_SECONDS)
+        t_hlo = time.perf_counter()
+        op_names = program_op_names(params, trace, sim)
+        hlo_s = time.perf_counter() - t_hlo
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         os.makedirs(TRACE_DIR, exist_ok=True)
         undo = _instrument_dispatch()
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.enable_hlo_proto = False
+        obs.enable_tracing()
         jax.profiler.start_trace(TRACE_DIR, profiler_options=opts)
+    n_compiles = clock.count
     setup_s = time.perf_counter() - t_main
     try:
         with _span("bench.window"):
@@ -410,6 +470,7 @@ def measure_program(c, seed, seconds, traced, t_main, check_chip=True):
             t_stop = time.perf_counter()
             jax.profiler.stop_trace()
             trace_stop_s = time.perf_counter() - t_stop
+            obs.enable_tracing(False)
             undo()
     compiled_in_window = clock.count - n_compiles
     clock.close()
@@ -418,25 +479,31 @@ def measure_program(c, seed, seconds, traced, t_main, check_chip=True):
                            f"measured window")
     peak = memory_peak()
     del sim
-    icount, rounds = window_counts(sims)
+    counters, round_ctrs = window_totals(sims)
+    icount, rounds = counters.get("icount", 0), round_ctrs["round_ctr"]
     dev = device_line()
     dev["memory_peak_bytes"] = peak
     out = {"correct": None, "attempted": len(sims), "failed": 0,
            "metrics": {}, "device": dev}
     if traced:
-        if HARNESS not in sys.path:
-            sys.path.insert(0, HARNESS)
+        import phases
         import xtrace
         t_red = time.perf_counter()
         xp = xtrace.find_xplane(TRACE_DIR)
         trace_mb = os.path.getsize(xp) / MB
-        devices, spans = xtrace.load(xp)
+        devices, spans = phases.load(xp, op_names)
         devices_seen = {k: len(v) for k, v in devices.items()}
-        red = xtrace.reduce(devices, spans)
+        red = xtrace.reduce(devices, [sp for sp in spans if sp[0].startswith(
+            xtrace.SPAN_PREFIX)])
+        split = phases.reduce(devices, spans)
+        del devices
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         ctx = {"busy_s": red.get("busy_s"), "window_s": red.get("window_s"),
                "rounds": rounds, "icount": icount, "sim_init_s": init_s,
-               "compile_s": compile_setup, "mode": mode}
+               "compile_s": compile_setup, "mode": mode,
+               "counters": counters, "round_ctrs": round_ctrs,
+               "phase_s": split.get("phases", {}),
+               "span_s": split.get("spans", {})}
         for m in c["per_layer"]:
             v = load_reader(m["name"])(ctx)
             if v is not None:
@@ -447,9 +514,12 @@ def measure_program(c, seed, seconds, traced, t_main, check_chip=True):
             out["breakdown"] = {"device_ops": red["device_ops"],
                                 "idle_gaps": red["idle_gaps"]}
         trace_read_s = time.perf_counter() - t_red
+        op_phase = split.get("op_phase", {})
         trace_info = {"stop_s": trace_stop_s, "read_s": trace_read_s,
-                      "xplane_mb": trace_mb,
-                      "device_planes": devices_seen}
+                      "hlo_s": hlo_s, "xplane_mb": trace_mb,
+                      "device_planes": devices_seen,
+                      "top_op_phase": {n: op_phase.get(n) for n, _ in
+                                       red.get("device_ops", [])}}
     else:
         trace_info = None
         values = {"sim_mips": icount / window_s / 1e6,
@@ -474,8 +544,10 @@ def measure_program(c, seed, seconds, traced, t_main, check_chip=True):
 def finish(out, sims, events, c):
     """The plain reference over the window's simulations; fills correct,
     failed and the compared numbers (the line's last key)."""
+    ref_cls = reference_class(c["config"])
     t_ref = time.perf_counter()
-    failed, compared = check(sims, reference_model(c["config"]), events)
+    failed, compared = check(sims, reference_model(c["config"]), events,
+                             ref_cls)
     out["window"]["reference_s"] = time.perf_counter() - t_ref
     out["failed"] = failed
     out["correct"] = failed == 0 and compared["simulations_compared"] > 0
@@ -486,6 +558,7 @@ def finish(out, sims, events, c):
 
 def measure(c, seed, seconds, traced, t_main, check_chip=True):
     """One run of a cell; returns the result line's dict."""
+    reference_class(c["config"])          # a missing one fails before set-up
     out, sims, events = measure_program(c, seed, seconds, traced, t_main,
                                         check_chip)
     return finish(out, sims, events, c)

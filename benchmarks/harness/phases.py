@@ -15,11 +15,23 @@ device plane, clipped to the ``bench.window`` span.  Each op counts its
 own time, the part no op nested in it covers, so the phases sum to the
 device's busy time.  A v5e trace gives each op its name, offset and
 duration only, so the op_names come from the HLO text of the executable
-that ran (``op_names_from_hlo``).  JAX's
-persistent compile cache keys a program without its debug info: an
-executable cached from a program that differs only in its scopes comes
-back with that program's metadata.  Compile the traced program with
-``jax_compilation_cache_include_metadata_in_key`` set.
+that ran (``op_names_from_hlo``): a traced run of the harness lowers and
+compiles the window's program once more in set-up, which finds it in
+jax's caches, and reads ``compile().as_text()``.
+
+The harness does not set ``jax_compilation_cache_include_metadata_in_key``
+(a traced run would pay a second cold compile).  JAX's persistent compile
+cache keys a program without its debug info, so an executable cached from
+a program that differs only in its scopes comes back with that program's
+metadata.  A checkout keeps its own cache (``<checkout>/.jax_cache``),
+with its own commit's executables, so this happens in one case only: two
+programs that differ only in their scopes sharing one
+``JAX_COMPILATION_CACHE_DIR``.  The split then follows the scopes of the
+program that was cached first.
+
+``load`` reads the trace once for both reductions: its ops carry the
+op_name as a fourth field, which ``xtrace.reduce`` ignores, and its spans
+are the harness's (``bench.*``) and the program's.
 
 ``PHASES`` is this module's own copy of the scope names: a scope the
 program renames keeps its new name here, beside the old one at 0, so a
@@ -133,11 +145,11 @@ def load(path: str, op_names: dict, device_plane=xtrace.DEVICE_PLANE,
          op_line: str = xtrace.OP_LINE):
     """(device ops per device plane, spans): ops as (name, start_ns,
     end_ns, op_name), the op_name from ``op_names``
-    (``op_names_from_hlo``); spans as (name, start_ns, end_ns) for
-    ``bench.window`` and the program's spans."""
+    (``op_names_from_hlo``); spans as (name, start_ns, end_ns) for the
+    harness's ``bench.*`` spans and the program's spans."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    wanted = set(PROGRAM_SPANS) | {xtrace.WINDOW_SPAN}
+    wanted = set(PROGRAM_SPANS)
     match = device_plane.match if hasattr(device_plane, "match") \
         else (lambda n: n.startswith(device_plane))
     devices, spans = {}, []
@@ -156,7 +168,8 @@ def load(path: str, op_names: dict, device_plane=xtrace.DEVICE_PLANE,
             devices[plane.name] = ops
         for ln in lines:
             for ev in ln.events:
-                if ev.name in wanted:
+                if ev.name in wanted \
+                        or ev.name.startswith(xtrace.SPAN_PREFIX):
                     spans.append((ev.name, ev.start_ns,
                                   ev.start_ns + ev.duration_ns))
     return devices, spans

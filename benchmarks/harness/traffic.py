@@ -6,11 +6,25 @@ benchmark so that later PRs cannot change the yardstick.  Each returns the
 [T, N] event arrays of graphite's trace format (op, addr, arg, arg2),
 padded with NOP.  A traffic file names its generator, its parameters, and
 overrides of them at a tile count (``scale``).
+
+``radix`` and ``fft`` are built in.  Any other generator is a file of its
+own, ``benchmarks/generators/<generator>.py``, which a later PR adds
+without editing this one.  It exposes ``generate(tiles, seed, params) ->
+{"ops", "addr", "arg", "arg2"}``, ``params`` being ``resolve()``'s output,
+and may import this module (the harness directory is on ``sys.path``)
+for ``_groups``, ``_one``, ``_pack`` and the address bases.
 """
 
 from __future__ import annotations
 
+import os
+
 import numpy as np
+
+import plugins
+
+GENERATOR_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "generators")
 
 PRIVATE_BASE = 0x1000_0000
 PRIVATE_SPAN = 0x0100_0000
@@ -154,7 +168,13 @@ def gen_fft(tiles: int, seed: int, points_per_tile: int) -> dict:
     return _pack(streams)
 
 
-GENERATORS = {"radix": gen_radix, "fft": gen_fft}
+BUILT_IN = ("radix", "fft")
+
+
+def generators() -> set:
+    """Every generator a traffic file may name: the built-in ones and the
+    files of ``GENERATOR_DIR``."""
+    return set(BUILT_IN) | plugins.names(GENERATOR_DIR)
 
 
 def resolve(traffic: dict, tiles: int) -> dict:
@@ -176,4 +196,5 @@ def generate(traffic: dict, tiles: int, seed: int) -> dict:
     if gen == "fft":
         ppt = p.get("points_per_tile") or (1 << p["points_log2"]) // tiles
         return gen_fft(tiles, seed, ppt)
-    raise ValueError(f"unknown generator {gen!r} (known: {sorted(GENERATORS)})")
+    return plugins.load(GENERATOR_DIR, gen, "generator").generate(
+        tiles, seed, p)

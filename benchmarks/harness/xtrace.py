@@ -1,11 +1,11 @@
 """Profiler trace -> device busy time, idle share, top operations, and the
 idle gaps named by the harness span that was open in each.
 
-Reads the ``.xplane.pb`` that ``jax.profiler`` writes, through
-``jax.profiler.ProfileData``.  Device planes are ``/device:*``; their
-operations come from the ``XLA Ops`` line where it exists.  The harness's
-own spans (``bench.*``, written with ``jax.profiler.TraceAnnotation``) are
-read from the host plane on the same clock.
+Works on what ``phases.load`` reads from the ``.xplane.pb`` that
+``jax.profiler`` writes: the ops of each device plane (``/device:*``;
+from the ``XLA Ops`` line where it exists) and the spans of the host
+plane on the same clock, of which the harness's own (``bench.*``, written
+with ``jax.profiler.TraceAnnotation``) name the idle gaps.
 """
 
 from __future__ import annotations
@@ -36,37 +36,13 @@ def short_name(name: str) -> str:
     return name.split(" = ", 1)[0].lstrip("%")[:64]
 
 
-def load(path: str, device_plane=DEVICE_PLANE, op_line: str = OP_LINE):
-    """(device ops per device plane, harness spans): ops as
-    (name, start_ns, end_ns) lists keyed by plane name, spans as
-    (name, start_ns, end_ns)."""
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
-    devices, spans = {}, []
-    match = device_plane.match if hasattr(device_plane, "match") \
-        else (lambda n: n.startswith(device_plane))
-    for plane in pd.planes:
-        lines = list(plane.lines)
-        if match(plane.name):
-            chosen = [ln for ln in lines if ln.name.startswith(op_line)] \
-                or lines
-            devices[plane.name] = [
-                (short_name(ev.name), ev.start_ns,
-                 ev.start_ns + ev.duration_ns)
-                for ln in chosen for ev in ln.events]
-        for ln in lines:
-            for ev in ln.events:
-                if ev.name.startswith(SPAN_PREFIX):
-                    spans.append((ev.name, ev.start_ns,
-                                  ev.start_ns + ev.duration_ns))
-    return devices, spans
-
-
 def clip(intervals, t0, t1):
-    """Intervals cut to [t0, t1]; empty ones dropped."""
+    """Intervals, (start, end) or ops (name, start, end, ...), cut to
+    [t0, t1]; empty ones dropped."""
     out = []
     for iv in intervals:
-        s, e = max(iv[-2], t0), min(iv[-1], t1)
+        lo, hi = (iv[0], iv[1]) if len(iv) == 2 else (iv[1], iv[2])
+        s, e = max(lo, t0), min(hi, t1)
         if e > s:
             out.append((s, e))
     return out
@@ -128,7 +104,8 @@ def leaves(ops):
 
 def reduce(devices, spans, top: int = 10) -> dict:
     """busy_s / window_s over the ``bench.window`` span, averaged over the
-    device planes, plus the breakdown lists."""
+    device planes, plus the breakdown lists.  Ops may carry more fields
+    after (name, start, end), as ``phases.load``'s do."""
     win = [sp for sp in spans if sp[0] == WINDOW_SPAN]
     if not win or not devices:
         return {}
@@ -137,7 +114,7 @@ def reduce(devices, spans, top: int = 10) -> dict:
     for ops in devices.values():
         iv = clip(ops, t0, t1)
         busy.append(busy_ns(iv) / 1e9)
-        for name, s, e in leaves(ops):
+        for name, s, e, *_ in leaves(ops):
             d = min(e, t1) - max(s, t0)
             if d > 0:
                 op_time[name] = op_time.get(name, 0.0) + d / 1e9

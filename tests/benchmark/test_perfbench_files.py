@@ -80,14 +80,20 @@ def test_config_loads_and_names_its_source(cfg):
     assert params.miss_chain == 0 and params.fast_forward == 0
 
 
+def check_traffic_file(t, name):
+    """A traffic file names itself, its public source, a generator that is
+    built in or a file of benchmarks/generators/, and a window mode."""
+    assert t["name"] == name and URL.search(t["source"])
+    assert t["generator"] in traffic.generators()
+    assert traffic.resolve(t, 64)["window"] in ("restart", "sustained")
+
+
 @pytest.mark.parametrize("name", sorted({w["traffic"]
                                          for w in BM["workloads"]}))
 def test_traffic_loads_and_names_its_source(name):
     t = core.load_json(os.path.join(ROOT, "benchmarks", "traffic",
                                     name + ".json"))
-    assert t["name"] == name and URL.search(t["source"])
-    assert t["generator"] in traffic.GENERATORS
-    assert traffic.resolve(t, 64)["window"] in ("restart", "sustained")
+    check_traffic_file(t, name)
 
 
 @pytest.mark.parametrize("wl", BM["workloads"], ids=lambda w: w["name"])

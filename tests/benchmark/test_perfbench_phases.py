@@ -152,6 +152,17 @@ def test_reduce_sums_own_time_in_window():
     assert phases.reduce({}, spans) == {}
 
 
+def test_one_load_serves_both_reductions():
+    """xtrace.reduce reads phases.load's ops, which carry their op_name
+    as a fourth field, as it reads its own (name, start, end) ones."""
+    ops = [("while.1", 0, 100, "jit(f)/while"), ("fusion.1", 0, 40, WALK),
+           ("fusion.2", 50, 70, RESOLVE_IN_COND), ("copy.3", 120, 130, None)]
+    spans = [("bench.window", 10, 125), ("bench.poll", 10, 125)]
+    four = xtrace.reduce({"/device:TPU:0": ops}, spans)
+    three = xtrace.reduce({"/device:TPU:0": [o[:3] for o in ops]}, spans)
+    assert four == three and four["busy_s"] > 0
+
+
 def test_recorded_cpu_trace(tmp_path):
     """A trace recorded here: ops of a scoped jitted function take their
     op_name from the compiled HLO's metadata, and the program's spans

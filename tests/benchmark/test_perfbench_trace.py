@@ -13,6 +13,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
 sys.path.insert(0, os.path.join(ROOT, "benchmarks", "harness"))
 
 import core  # noqa: E402
+import phases  # noqa: E402
 import xtrace  # noqa: E402
 
 
@@ -70,7 +71,7 @@ def test_recorded_cpu_trace(tmp_path):
                 y.block_until_ready()
             time.sleep(0.01)
     jax.profiler.stop_trace()
-    devices, spans = xtrace.load(xtrace.find_xplane(str(tmp_path)),
+    devices, spans = phases.load(xtrace.find_xplane(str(tmp_path)), {},
                                  device_plane="/host:CPU",
                                  op_line="tf_XLAPjRtCpuClient")
     names = [s[0] for s in spans]
