@@ -33,7 +33,11 @@ from graphite_tpu.engine.state import SimState, make_state, \
     sharers_from_planes
 from graphite_tpu.params import SimParams
 
-_SCHEMA_VERSION = 28  # v28: the full-map sharer bitmap is stored as a
+_SCHEMA_VERSION = 29  # v29: per-link queue rings (link_mem/*,
+#   link_user/*: noc_flight.LinkRings) replace the carried link horizons
+#   (link_free_mem / link_free_user); a v26-v28 file restores with empty
+#   link rings (its horizons are dropped);
+#   v28: the full-map sharer bitmap is stored as a
 #   flat, entry-major uint32 store (SimState.dir_sharers); a v26/v27
 #   [W*A, F] uint64 plane array converts on load (sharers_from_planes);
 #   v27: streaming segmented ingest (round 16) —
@@ -150,10 +154,11 @@ def _open_checkpoint(path: str):
 
 
 # v27 added ingest metadata WITHOUT touching state-leaf semantics, so
-# v26 (whole-trace) checkpoints restore unchanged, and v28 only changed
-# the sharer store's layout, which converts on load; anything older
-# predates the routed-resolve counter semantics and is rejected.
-_COMPATIBLE_SCHEMAS = (26, 27, 28)
+# v26 (whole-trace) checkpoints restore unchanged, v28 only changed
+# the sharer store's layout, which converts on load, and v29's link rings
+# start empty; anything older predates the routed-resolve counter
+# semantics and is rejected.
+_COMPATIBLE_SCHEMAS = (26, 27, 28, 29)
 
 
 def _check_schema(path: str, z) -> None:
@@ -166,10 +171,14 @@ def _check_schema(path: str, z) -> None:
 def _load_leaves(path: str, z, template: SimState) -> SimState:
     """Fill ``template``'s leaves from the archive, shape-verified."""
     arrays, treedef = _flatten_with_paths(template)
-    planes = int(z["__meta_schema"]) < 28
+    schema = int(z["__meta_schema"])
+    planes = schema < 28
     leaves = []
     for key, tmpl in arrays.items():
         if key.startswith("__meta"):
+            continue
+        if schema < 29 and key.startswith(("link_mem/", "link_user/")):
+            leaves.append(jnp.zeros(tmpl.shape, tmpl.dtype))
             continue
         if key not in z:
             raise ValueError(f"checkpoint missing field {key!r}")

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from graphite_tpu.engine import cache as cachemod
 from graphite_tpu.engine import dense
 from graphite_tpu.engine import noc
+from graphite_tpu.engine import noc_flight
 from graphite_tpu.engine.kernels import dispatch as kdispatch
 from graphite_tpu.engine.kernels import window as kwindow
 from graphite_tpu.engine.state import (
@@ -626,19 +627,18 @@ def _complex_slot(params: SimParams, vp: VariantParams, state: SimState,
         # the message can't occupy the slot before the recv that freed it.
         slot_freed = st.ch_time[slot_idx, rows, dst]
         depart = jnp.maximum(clk + cycle_ps, slot_freed)
-        if params.net_user.model == "emesh_hop_by_hop":
+        if noc_flight.contended(params.net_user):
             # CAPI data packets contend per link on the user mesh
             # (reference: the USER network's own hop-by-hop model +
             # queue models, network_model_emesh_hop_by_hop.cc).
-            from graphite_tpu.engine import noc_flight
             fl = noc_flight.flight(
                 params.net_user, params.mesh_width, params.mesh_height,
                 rows.astype(jnp.int32), dst, depart,
                 noc.num_flits(jnp.maximum(arg, 0),
                               vp.net_user.flit_width_bits),
-                is_send & active, st.link_free_user, p_nu,
+                is_send & active, st.link_user, p_nu,
                 vnet=vp.net_user)
-            st = st._replace(link_free_user=fl.link_free)
+            st = st._replace(link_user=fl.rings)
             c = c._replace(net_link_wait_ps=c.net_link_wait_ps
                            + jnp.where(is_send & active & en,
                                        fl.wait_ps, 0))

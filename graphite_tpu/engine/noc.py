@@ -9,10 +9,12 @@ common/network/network_model.h:39-207 and common/network/models/):
     (router + link delay) + flit serialization, no contention
     (network_model_emesh_hop_counter.cc:143).
   * ``emesh_hop_by_hop`` — per-link contention, modeled in
-    engine/noc_flight.py (hop-by-hop flights over FCFS link horizons
-    carried in ``SimState.link_free_mem``); resolve prices every memory-
-    network unicast leg through it when this model is selected.  The
-    functions here still supply the zero-load forms for multicasts.
+    engine/noc_flight.py (hop-by-hop flights priced by the configured
+    link queue model, over queues carried in ``SimState.link_mem``);
+    resolve prices every memory-network unicast leg through it when this
+    model and its queue model are selected.  The functions here still
+    supply the zero-load forms for multicasts, and for the model with its
+    queue model off.
   * ``atac`` — hybrid optical broadcast network, analytic form in
     engine/noc_atac.py (network_model_atac.cc); dispatched from the
     functions here.

@@ -8,34 +8,28 @@ queue_model{_basic,_history_list,_history_tree,_m_g_1}.{h,cc},
 once per packet.
 
 The TPU engine processes a whole batch of requests per step, so the native
-formulation is a *segmented FCFS sweep*: sort requests by (resource,
-arrival), then within each segment the exact FCFS completion times have the
+formulation is a *segmented FCFS sweep*: requests serialize per resource in
+(arrival, row) order, and the exact FCFS completion times have the
 associative closed form
 
     end_i = S_i + max_{j<=i}(a_j - S_{j-1})        (S = prefix sum of service)
 
-computed with one cumsum + one segmented running-max — no sequential loop.
-For in-order arrivals this is exactly what history_list computes; the
-moving-average and M/G/1 variants are strictly coarser approximations of
-the same quantity, so all config queue-model choices map here (divergence:
-no interval *interleaving* of out-of-order arrivals within one batch —
-arrivals are sorted first, which the reference's interleaving_enabled mode
-also effectively permits).
+computed as one dense pairwise compare-reduce (``_serial_fcfs``) — no
+sequential loop.  ``probe`` dispatches on the configured type: the history
+types serve on top of a ring of busy intervals per resource
+(``fcfs_ring``), ``basic`` on top of one carried horizon (``basic_ring``),
+``m_g_1`` from carried moments (``mg1_delay``).  Divergence: no interval
+*interleaving* of out-of-order arrivals within one batch — arrivals are
+sorted first, which the reference's interleaving_enabled mode also
+effectively permits.  Callers: the DRAM controllers (engine/resolve.py)
+and the mesh links (engine/noc_flight.py).
 """
 
 from __future__ import annotations
 
 from typing import NamedTuple
 
-import jax
 import jax.numpy as jnp
-
-
-class FcfsResult(NamedTuple):
-    start: jnp.ndarray     # [K] int64 service start times (original order)
-    end: jnp.ndarray       # [K] int64 completion times (original order)
-    delay: jnp.ndarray     # [K] int64 queueing delay (start - arrival)
-    free_at: jnp.ndarray   # [R] int64 updated per-resource horizon
 
 
 def _cumsum_doubling(x: jnp.ndarray) -> jnp.ndarray:
@@ -76,6 +70,34 @@ def _containing_end(res, t, svc, ring_start, ring_end):
     return jnp.max(jnp.where(overlap, re, t[None, :]), axis=0)
 
 
+_BIG = 2**62
+
+
+def _merged(res, valid, start, end, C):
+    """Per resource of C: the earliest start and the latest end of its
+    valid rows, and whether it has any (lo BIG and hi -BIG where not).
+    One scatter-max over both fields (the start negated): the TPU runs a
+    scatter update by update, a two-field update as one."""
+    idx = jnp.clip(res, 0, C - 1).astype(jnp.int32)
+    vals = jnp.stack([jnp.where(valid, -start, -_BIG),
+                      jnp.where(valid, end, -_BIG)]).astype(jnp.int64)
+    tbl = jnp.full((2, C), -_BIG, jnp.int64).at[:, idx].max(vals)
+    return -tbl[0], tbl[1], tbl[1] > 0
+
+
+def _ring_record(ring_start, ring_end, ring_ptr, lo, hi, has):
+    """Write [lo, hi) into each resource's next ring slot where ``has``
+    (the oldest interval is overwritten).  Every resource column takes at
+    most one write, so this is a dense select over [R, C], not a scatter:
+    a column scatter runs update by update on the TPU."""
+    R = ring_start.shape[0]
+    put = has[None, :] & (jnp.arange(R, dtype=jnp.int32)[:, None]
+                          == (ring_ptr % R)[None, :])
+    return (jnp.where(put, lo[None, :], ring_start),
+            jnp.where(put, hi[None, :], ring_end),
+            ring_ptr + has.astype(jnp.int32))
+
+
 def fcfs_ring(resource: jnp.ndarray, arrival: jnp.ndarray,
               service: jnp.ndarray, valid: jnp.ndarray,
               ring_start: jnp.ndarray, ring_end: jnp.ndarray,
@@ -103,11 +125,9 @@ def fcfs_ring(resource: jnp.ndarray, arrival: jnp.ndarray,
     occ_*: optional occupancy-only rows (writebacks): they insert busy
     intervals but take no delay and return no times.
     """
-    K = resource.shape[0]
-    R, C = ring_start.shape
+    C = ring_start.shape[1]
     res_eff = jnp.where(valid, resource, C).astype(jnp.int32)
     res_g = jnp.minimum(res_eff, C - 1)
-    idx = jnp.arange(K, dtype=jnp.int32)
     svc = jnp.where(valid, service, 0)
 
     # Interval-resolved base: chase overlapping-interval ends a few times
@@ -119,34 +139,15 @@ def fcfs_ring(resource: jnp.ndarray, arrival: jnp.ndarray,
         base = _containing_end(res_g, base, svc, ring_start, ring_end)
     base = jnp.where(valid, base, arrival)
 
-    # Exact within-batch serialization (same dense pairwise closed form
-    # as `fcfs`, with the per-row interval base).
-    same = valid[None, :] & valid[:, None] \
-        & (res_eff[None, :] == res_eff[:, None])
-    earlier = same & ((arrival[None, :] < arrival[:, None])
-                      | ((arrival[None, :] == arrival[:, None])
-                         & (idx[None, :] < idx[:, None])))
-    S_prev = jnp.sum(jnp.where(earlier, svc[None, :], 0), axis=1)
-    cand = base - S_prev
-    self_or_earlier = earlier | (jnp.eye(K, dtype=bool) & valid[:, None])
-    run = jnp.max(jnp.where(self_or_earlier, cand[None, :],
-                            jnp.int64(-(2**62))), axis=1)
-    start = run + S_prev
-    end = start + svc
+    # Exact within-batch serialization, with the per-row interval base.
+    start, end = _serial_fcfs(res_eff, base, arrival, svc, valid, C)
     delay = jnp.where(valid, start - arrival, 0)
 
     # ---- record busy intervals: one merged [min start, max end] per
     # (resource, batch) for the requests, one more for the occupancy rows.
-    BIG = jnp.int64(2**62)
-
     def merged(res_m, valid_m, s_m, e_m):
-        lo = jnp.full((C,), BIG, jnp.int64).at[
-            jnp.where(valid_m, res_m, C)].min(s_m, mode="drop")
-        hi = jnp.zeros((C,), jnp.int64).at[
-            jnp.where(valid_m, res_m, C)].max(e_m, mode="drop")
-        return lo, hi, hi > 0
+        return _merged(res_m, valid_m, s_m, e_m, C)
 
-    cols = jnp.arange(C, dtype=jnp.int32)
     if record_split > 1:
         # Split-record (the miss-chain replay's batches): one merged
         # interval per batch is fine when a batch's arrivals span less
@@ -162,40 +163,23 @@ def fcfs_ring(resource: jnp.ndarray, arrival: jnp.ndarray,
         # per controller per iteration and halves the phantom span
         # otherwise, at one extra ring slot per batch.
         loF, hiF, _hasF = merged(res_eff, valid, start, end)
-        # (loF is the BIG sentinel on empty controllers — compute the
-        # midpoint via the half-difference so it can't overflow.)
+        # (loF is the BIG sentinel on empty controllers, hiF -BIG —
+        # compute the midpoint via the half-difference so it can't
+        # overflow.)
         mid = loF + (jnp.maximum(hiF, loF) - loF) // 2    # [C]
         grpA = valid & (start < mid[res_g])
         groups = (grpA, valid & ~grpA)
     else:
         groups = (valid,)
     for grp in groups:
-        loG, hiG, hasG = merged(res_eff, grp, start, end)
-        slotG = ring_ptr % R
-        ring_start = ring_start.at[
-            jnp.where(hasG, slotG, R), cols].set(
-            jnp.where(hasG, loG, 0), mode="drop")
-        ring_end = ring_end.at[
-            jnp.where(hasG, slotG, R), cols].set(
-            jnp.where(hasG, hiG, 0), mode="drop")
-        ring_ptr = ring_ptr + hasG.astype(jnp.int32)
+        ring_start, ring_end, ring_ptr = _ring_record(
+            ring_start, ring_end, ring_ptr,
+            *merged(res_eff, grp, start, end))
     if occ_res is not None:
-        occ_end = occ_arr + occ_svc
-        lo2, hi2, has2 = merged(
-            jnp.where(occ_valid, occ_res, C).astype(jnp.int32),
-            occ_valid, occ_arr, occ_end)
-    else:
-        lo2 = hi2 = None
-        has2 = jnp.zeros((C,), dtype=bool)
-    if occ_res is not None:
-        slot2 = ring_ptr % R
-        ring_start = ring_start.at[
-            jnp.where(has2, slot2, R), cols].set(jnp.where(has2, lo2, 0),
-                                                 mode="drop")
-        ring_end = ring_end.at[
-            jnp.where(has2, slot2, R), cols].set(jnp.where(has2, hi2, 0),
-                                                 mode="drop")
-        ring_ptr = ring_ptr + has2.astype(jnp.int32)
+        ring_start, ring_end, ring_ptr = _ring_record(
+            ring_start, ring_end, ring_ptr,
+            *merged(jnp.where(occ_valid, occ_res, C).astype(jnp.int32),
+                    occ_valid, occ_arr, occ_arr + occ_svc))
     return RingFcfsResult(start=jnp.where(valid, start, 0),
                           end=jnp.where(valid, end, 0),
                           delay=delay, ring_start=ring_start,
@@ -207,22 +191,10 @@ def insert_busy(ring_start: jnp.ndarray, ring_end: jnp.ndarray,
                 svc, valid: jnp.ndarray):
     """Occupancy-only insertion (writebacks off the critical path): one
     merged busy interval per (resource, call).  Returns updated rings."""
-    R, C = ring_start.shape
-    BIG = jnp.int64(2**62)
     svc = jnp.broadcast_to(jnp.asarray(svc, jnp.int64), t0.shape)
-    r_eff = jnp.where(valid, res, C).astype(jnp.int32)
-    lo = jnp.full((C,), BIG, jnp.int64).at[r_eff].min(t0, mode="drop")
-    hi = jnp.zeros((C,), jnp.int64).at[r_eff].max(t0 + svc, mode="drop")
-    has = hi > 0
-    cols = jnp.arange(C, dtype=jnp.int32)
-    slot = ring_ptr % R
-    ring_start = ring_start.at[
-        jnp.where(has, slot, R), cols].set(jnp.where(has, lo, 0),
-                                           mode="drop")
-    ring_end = ring_end.at[
-        jnp.where(has, slot, R), cols].set(jnp.where(has, hi, 0),
-                                           mode="drop")
-    return ring_start, ring_end, ring_ptr + has.astype(jnp.int32)
+    return _ring_record(ring_start, ring_end, ring_ptr,
+                        *_merged(res, valid, t0, t0 + svc,
+                                 ring_start.shape[1]))
 
 
 # Queue-model types the config may select (reference factory
@@ -250,7 +222,10 @@ def _serial_fcfs(res_eff, base, arrival, svc, valid, C, earlier=None):
     """Exact FCFS ends for rows serialized per resource in (arrival,
     index) order, each starting no earlier than its own ``base`` — the
     closed form end_i = S_i + max_{j<=i}(base_j - S_{j-1}) as a dense
-    pairwise compare (see ``fcfs`` for the sort-free rationale).
+    pairwise compare: sort-free, because XLA:TPU lowers sorts to
+    serialized while-loops of dynamic-update-slices (profiled ~31 ms per
+    [2048] lexsort), while a [K, K] masked compare-reduce is a few fused
+    vector ops.
     ``earlier`` may carry a precomputed ordering mask (callers that
     already built it for the moving average avoid a second [K, K] pass).
     Returns (start, end)."""
@@ -490,48 +465,3 @@ def occupy(qtype: str, ring_start, ring_end, ring_ptr, moments,
         return ring_start, ring_end, ring_ptr, m
     raise ValueError(f"unknown queue model type {qtype!r} "
                      f"(valid: {', '.join(VALID_TYPES)})")
-
-
-def fcfs(resource: jnp.ndarray, arrival: jnp.ndarray, service: jnp.ndarray,
-         valid: jnp.ndarray, free_at: jnp.ndarray) -> FcfsResult:
-    """Exact FCFS service of a request batch over shared resources.
-
-    resource: [K] int32 resource id per request (e.g. home memory
-      controller, reference dram_cntlr.h:12-51; or NoC link id).
-    arrival:  [K] int64 ps.
-    service:  [K] int64 ps occupancy per request.
-    valid:    [K] bool — invalid requests get zero delay and do not occupy.
-    free_at:  [R] int64 current per-resource busy horizon (carried across
-      batches — the queue model's memory of earlier traffic).
-    """
-    K = resource.shape[0]
-    R = free_at.shape[0]
-    res_eff = jnp.where(valid, resource, R).astype(jnp.int32)
-    idx = jnp.arange(K, dtype=jnp.int32)
-    svc = jnp.where(valid, service, 0)
-    # Dense pairwise form of the same closed-form recurrence — sort-free,
-    # because XLA:TPU lowers sorts to serialized while-loops of
-    # dynamic-update-slices (profiled ~31 ms per [2048] lexsort), while a
-    # [K, K] masked compare-reduce is a few fused vector ops.
-    #   earlier[i, j] <=> j is served before i on the same resource
-    #   (FCFS by arrival, ties by row index).
-    same = valid[None, :] & valid[:, None] \
-        & (res_eff[None, :] == res_eff[:, None])
-    earlier = same & ((arrival[None, :] < arrival[:, None])
-                      | ((arrival[None, :] == arrival[:, None])
-                         & (idx[None, :] < idx[:, None])))
-    # Exclusive prefix of service in service order.
-    S_prev = jnp.sum(jnp.where(earlier, svc[None, :], 0), axis=1)
-    base = jnp.maximum(arrival, free_at[jnp.minimum(res_eff, R - 1)])
-    cand = base - S_prev
-    # Running max over each row's predecessors (and itself).
-    self_or_earlier = earlier | (jnp.eye(K, dtype=bool) & valid[:, None])
-    run = jnp.max(jnp.where(self_or_earlier, cand[None, :],
-                            jnp.int64(-(2**62))), axis=1)
-    start = run + S_prev
-    end = start + svc
-    delay = jnp.where(valid, start - arrival, 0)
-    new_free = free_at.at[res_eff].max(jnp.where(valid, end, 0), mode="drop")
-    return FcfsResult(start=jnp.where(valid, start, 0),
-                      end=jnp.where(valid, end, 0),
-                      delay=delay, free_at=new_free)

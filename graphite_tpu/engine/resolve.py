@@ -621,8 +621,7 @@ def resolve_memory(params: SimParams, vp: VariantParams,
                                vp.net_memory.flit_width_bits)
     dense_tables = T * H <= _DENSE_MAX_ELEMS
     slots_p = jnp.arange(max(P, 1), dtype=jnp.int32)[:, None]
-    contended = (params.net_memory.model == "emesh_hop_by_hop"
-                 and params.net_memory.queue_model_enabled)
+    contended = noc_flight.contended(params.net_memory)
 
     # Blocking-semantics chain fast pass first: replays whole banked
     # chains sequentially inside ONE engine round (fori over chain
@@ -1047,25 +1046,22 @@ def resolve_memory(params: SimParams, vp: VariantParams,
 
         # ---- latency assembly (SURVEY.md 3.3's round trips).  Unicast
         # legs are either zero-load closed forms (magic/emesh_hop_counter)
-        # or, under emesh_hop_by_hop, per-link FCFS-contended flights
-        # (engine/noc_flight.py) threading the link horizons through every
-        # leg in dependency order: request -> victim flush -> owner leg ->
-        # reply.  Invalidation multicasts stay zero-load (the reference's
-        # broadcast-tree option likewise bypasses per-hop unicast queues).
-        # Contention requires both the hop-by-hop model AND its queue model
-        # (reference: hop-by-hop with queue_model/enabled=false charges
-        # per-hop latency with no contention — identical to hop_counter).
-        contended = (params.net_memory.model == "emesh_hop_by_hop"
-                     and params.net_memory.queue_model_enabled)
+        # or, under emesh_hop_by_hop, flights priced by the configured
+        # link queue model (engine/noc_flight.py), threading the link
+        # queues through every leg in dependency order: request -> victim
+        # flush -> owner leg -> reply.  Invalidation multicasts stay
+        # zero-load (the reference's broadcast-tree option likewise
+        # bypasses per-hop unicast queues).  Contention requires both the
+        # hop-by-hop model AND its queue model (noc_flight.contended).
         link_wait = jnp.zeros(T, dtype=jnp.int64)
-        lf = state.link_free_mem
+        lr = state.link_mem
         rows32 = rows.astype(jnp.int32)
         if contended:
             fr = noc_flight.flight(
                 params.net_memory, params.mesh_width, params.mesh_height,
-                rows32, home, issue, flits_req, win, lf, p_net,
+                rows32, home, issue, flits_req, win, lr, p_net,
                 vnet=vp.net_memory)
-            lf = fr.link_free
+            lr = fr.rings
             link_wait = link_wait + fr.wait_ps
             arrive = jnp.maximum(fr.arrival, line_floor)
         else:
@@ -1081,13 +1077,13 @@ def resolve_memory(params: SimParams, vp: VariantParams,
             dep_ev = arrive + dir_ps
             e1 = noc_flight.flight(
                 params.net_memory, params.mesh_width, params.mesh_height,
-                home, vown_c, dep_ev, flits_req, ev_rt, lf, p_net_home,
+                home, vown_c, dep_ev, flits_req, ev_rt, lr, p_net_home,
                 vnet=vp.net_memory)
             e2 = noc_flight.flight(
                 params.net_memory, params.mesh_width, params.mesh_height,
                 vown_c, home, e1.arrival + l2_vown_ps, flits_data, ev_rt,
-                e1.link_free, p_net_vown, vnet=vp.net_memory)
-            lf = e2.link_free
+                e1.rings, p_net_vown, vnet=vp.net_memory)
+            lr = e2.rings
             link_wait = link_wait + e1.wait_ps + e2.wait_ps
             evict_m_ps = jnp.where(ev_rt, e2.arrival - dep_ev, 0)
         else:
@@ -1121,13 +1117,13 @@ def resolve_memory(params: SimParams, vp: VariantParams,
         if contended:
             g1 = noc_flight.flight(
                 params.net_memory, params.mesh_width, params.mesh_height,
-                home, owner, t_dir, flits_req, owner_leg, lf, p_net_home,
+                home, owner, t_dir, flits_req, owner_leg, lr, p_net_home,
                 vnet=vp.net_memory)
             g2 = noc_flight.flight(
                 params.net_memory, params.mesh_width, params.mesh_height,
                 owner, home, g1.arrival + l2_own_ps, flits_data, owner_leg,
-                g1.link_free, p_net_own, vnet=vp.net_memory)
-            lf = g2.link_free
+                g1.rings, p_net_own, vnet=vp.net_memory)
+            lr = g2.rings
             link_wait = link_wait + g1.wait_ps + g2.wait_ps
             owner_ps = jnp.where(owner_leg, g2.arrival - t_dir, 0)
         else:
@@ -1192,12 +1188,11 @@ def resolve_memory(params: SimParams, vp: VariantParams,
         if contended:
             rr = noc_flight.flight(
                 params.net_memory, params.mesh_width, params.mesh_height,
-                home, rows32, t_data, flits_data, win, lf, p_net_home,
+                home, rows32, t_data, flits_data, win, lr, p_net_home,
                 vnet=vp.net_memory)
-            lf = rr.link_free
             link_wait = link_wait + rr.wait_ps
             reply_done = rr.arrival
-            state = state._replace(link_free_mem=lf)
+            state = state._replace(link_mem=rr.rings)
         else:
             reply_done = t_data + reply_ps
 

@@ -393,14 +393,18 @@ class SimState(NamedTuple):
     # the rows of the configured [dram/queue_model] type are consumed.
     dram_qacc: jnp.ndarray         # [6, T] float64
 
-    # -- mesh link horizons (emesh_hop_by_hop contention; reference:
-    # per-link queue models in network_model_emesh_hop_by_hop.cc)
-    link_free_mem: jnp.ndarray  # [NUM_DIRS, T] int64 directed-link horizons
-    # User-network link horizons (CAPI data traffic under
-    # network/user = emesh_hop_by_hop; [NUM_DIRS, 0] otherwise).  MCP
-    # control trips stay zero-load: the reference routes those over the
-    # SYSTEM network, which has its own (magic by default) model.
-    link_free_user: jnp.ndarray
+    # -- mesh link queues (emesh_hop_by_hop contention; reference:
+    # per-link queue models in network_model_emesh_hop_by_hop.cc): per
+    # directed link, the configured queue model's busy-interval ring, as
+    # the DRAM queue's, of max_list_size slots (``basic``: one slot, its
+    # horizon in the ends); zero-sized when the network is not contended
+    # (noc_flight.contended).
+    link_mem: noc_flight.LinkRings
+    # The user network's (CAPI data traffic under network/user =
+    # emesh_hop_by_hop).  MCP control trips stay zero-load: the reference
+    # routes those over the SYSTEM network, which has its own (magic by
+    # default) model.
+    link_user: noc_flight.LinkRings
 
     # -- sync objects, global (reference: sync_server.h SimMutex/SimBarrier/
     # SimCond)
@@ -799,9 +803,8 @@ def make_state(params: SimParams,
         dram_ring_end=jnp.zeros((DRAM_RING_SLOTS, T), dtype=jnp.int64),
         dram_ring_ptr=jnp.zeros(T, dtype=jnp.int32),
         dram_qacc=jnp.zeros((6, T), dtype=jnp.float64),
-        link_free_mem=noc_flight.make_link_free(T),
-        link_free_user=noc_flight.make_link_free(
-            T if params.net_user.model == "emesh_hop_by_hop" else 0),
+        link_mem=noc_flight.make_link_rings(params.net_memory, T),
+        link_user=noc_flight.make_link_rings(params.net_user, T),
         lock_holder=jnp.zeros(max_mutexes, dtype=jnp.int32),
         lock_free_at=jnp.zeros(max_mutexes, dtype=jnp.int64),
         bar_count=jnp.zeros(max_barriers, dtype=jnp.int32),

@@ -24,3 +24,9 @@ RESOLVE_SYNC = "engine.resolve_sync"      # resolve.resolve's sync_pass
 
 PHASES = (BARRIER, SCHEDULE, SAMPLE, WINDOW_WALK, COMPLEX_SLOT,
           FAST_FORWARD, RESOLVE_MEMORY, RESOLVE_SYNC)
+
+# Sub-phase: the contended mesh's flight loop (noc_flight.flight), nested
+# in RESOLVE_MEMORY (memory network) or COMPLEX_SLOT (user network).  Being
+# the innermost ``engine.*`` scope of its ops, it takes their time out of
+# the enclosing phase.  Only a contended network traces it.
+NOC_FLIGHT = "engine.noc_flight"

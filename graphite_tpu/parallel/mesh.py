@@ -122,7 +122,6 @@ _TILE_AXIS_BY_FIELD = {
     "mq_delta": 1, "mq_extra": 1,    # (blocking chain replay, round 7)
     "lq_ready": 1, "sq_ready": 1,    # [entries, T]
     "dram_ring_start": 1, "dram_ring_end": 1,   # [R, T]
-    "link_free_mem": 1,              # [NUM_DIRS, T]
     "stat_icount": 1,                # [S, T] progress-trace snapshots
     "tel_cursor": 1,                 # [S, T] telemetry cursor snapshots
     "tel_pend": 1,                   # [S, T] telemetry pend_kind snapshots

@@ -551,6 +551,9 @@ class NetworkParams:
     queue_model_type: str
     broadcast_tree_enabled: bool
     atac: Optional[AtacParams] = None
+    # Busy intervals each link queue keeps: [queue_model/<type>]
+    # max_list_size for the history types, 1 (the horizon) for basic.
+    queue_slots: int = 1
 
     @classmethod
     def from_config(cls, cfg: Config, which: str, num_tiles: int,
@@ -562,6 +565,16 @@ class NetworkParams:
         atac = None
         if model == "atac":
             atac = AtacParams.from_config(cfg, num_tiles, net_freq_ghz)
+        qtype = _link_queue_model_type(
+            cfg.get_str(f"{sec}/queue_model/type", "history_tree"),
+            f"{sec}/queue_model/type")
+        slots = 1
+        if qtype != "basic":
+            slots = cfg.get_int(f"queue_model/{qtype}/max_list_size", 100)
+            if slots <= 0:
+                raise ConfigError(
+                    f"queue_model/{qtype}/max_list_size must be positive, "
+                    f"got {slots}")
         return cls(
             model=model,
             flit_width_bits=cfg.get_int(f"{sec}/flit_width", 64),
@@ -576,14 +589,14 @@ class NetworkParams:
             # Link queues accept the reference factory's three types
             # (basic/history_list/history_tree — queue_model.cc:18-37;
             # m_g_1 is DRAM-only here, as in the reference where it only
-            # exists inside history_tree).  All three map to the exact
-            # per-link FCFS sweep (noc_flight.py) — exact FCFS == basic
-            # for in-order arrivals and >= history fidelity otherwise.
-            queue_model_type=_link_queue_model_type(
-                cfg.get_str(f"{sec}/queue_model/type", "history_tree"),
-                f"{sec}/queue_model/type"),
+            # exists inside history_tree).  noc_flight.flight probes each
+            # hop through queue_models.probe by this type: the history
+            # types over busy-interval rings, basic over one carried
+            # horizon per link.
+            queue_model_type=qtype,
             broadcast_tree_enabled=cfg.get_bool(f"{sec}/broadcast_tree_enabled", False),
             atac=atac,
+            queue_slots=slots,
         )
 
 

@@ -110,6 +110,7 @@ STRUCTURAL_LEAVES = frozenset({
     "segment_events",             # streaming ingest capacity — sizes the
     #   resident segment arrays (a SHAPE), so it can never ride a
     #   vmapped variant axis
+    "net_user.queue_slots", "net_memory.queue_slots",  # link ring depth
 } | {f"{c}.{f}" for c in ("l1i", "l1d", "l2") for f in _CACHE_STRUCT}
   | {f"{n}.atac.{f}" for n in ("net_user", "net_memory")
      for f in _ATAC_STRUCT})

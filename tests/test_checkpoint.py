@@ -390,3 +390,45 @@ def test_v27_sharer_planes_restore_identical(tmp_path):
     assert s_full.completion_time_ps == s_res.completion_time_ps
     for f, a in s_full.counters.items():
         assert np.array_equal(a, s_res.counters[f]), f
+
+
+def test_v28_restores_with_empty_link_rings(tmp_path):
+    """Schema 29 carries the contended mesh's per-link queue rings and
+    restores them as saved; a v28 checkpoint (carried link horizons, no
+    rings) restores with the rings empty."""
+    cfg = load_config()
+    cfg.set("general/total_cores", 8)
+    cfg.set("network/memory", "emesh_hop_by_hop")
+    params = SimParams.from_config(cfg)
+    trace = synth.gen_migratory(8, lines=4, rounds=2)
+    ck = str(tmp_path / "v29.npz")
+    Simulator(params, trace).save_checkpoint(ck)
+    with np.load(ck) as z:
+        saved = {k: z[k] for k in z.files}
+    keys = [k for k in saved if k.startswith(("link_mem/", "link_user/"))]
+    assert len(keys) == 6
+    assert params.net_memory.queue_slots == 100
+    assert saved["link_mem/start"].shape == (100, 4 * 8)
+    assert saved["link_user/start"].size == 0
+    filled = {k: (np.arange(saved[k].size) + 1).astype(saved[k].dtype)
+              .reshape(saved[k].shape) for k in keys}
+
+    def restored(arrays, name):
+        path = str(tmp_path / name)
+        with open(path, "wb") as f:
+            np.savez(f, **arrays)
+        sim = Simulator(params, trace)
+        sim.restore_checkpoint(path)
+        return {k: np.asarray(getattr(getattr(sim.state, k.split("/")[0]),
+                                      k.split("/")[1])) for k in keys}
+
+    got = restored(dict(saved, **filled), "rings.npz")
+    for k in keys:
+        np.testing.assert_array_equal(got[k], filled[k])
+    v28 = {k: v for k, v in saved.items() if k not in keys}
+    v28["link_free_mem"] = np.full((4, 8), 123, np.int64)
+    v28["link_free_user"] = np.zeros((4, 0), np.int64)
+    v28["__meta_schema"] = np.int64(28)
+    got = restored(v28, "v28.npz")
+    for k in keys:
+        assert got[k].shape == saved[k].shape and not got[k].any(), k

@@ -1,10 +1,15 @@
 """emesh_hop_by_hop link contention tests.
 
 Pin the contended-mesh contract (reference:
-network_model_emesh_hop_by_hop.cc:146 + per-link queue models): same-link
-packets serialize in FCFS order against carried link horizons; an idle
-mesh reproduces the zero-load hop-counter latency exactly.
+network_model_emesh_hop_by_hop.cc:146 + per-link queue models): each hop
+probes the link's configured queue model (``history_list`` /
+``history_tree``: a ring of busy intervals, so a later batch's earlier
+packet fits an idle gap; ``basic``: one carried horizon, so it waits);
+same-link packets of one batch serialize in FCFS order; an idle mesh
+reproduces the zero-load hop-counter latency exactly.
 """
+
+import dataclasses
 
 import pytest
 import jax.numpy as jnp
@@ -20,7 +25,7 @@ from graphite_tpu.params import NetworkParams, SimParams
 NET = NetworkParams(model="emesh_hop_by_hop", flit_width_bits=64,
                     router_delay_cycles=1, link_delay_cycles=1,
                     queue_model_enabled=True, queue_model_type="history_tree",
-                    broadcast_tree_enabled=False)
+                    broadcast_tree_enabled=False, queue_slots=100)
 
 
 def make_params(tiles=16, **over):
@@ -32,17 +37,20 @@ def make_params(tiles=16, **over):
     return SimParams.from_config(cfg)
 
 
-def _fly(src, dst, depart, flits, mesh_w, mesh_h, T, link_free=None,
-         active=None):
+QTYPES = ("basic", "history_list", "history_tree")
+
+
+def _fly(src, dst, depart, flits, mesh_w, mesh_h, T, rings=None,
+         active=None, net=NET):
     src = jnp.asarray(src, jnp.int32)
     K = src.shape[0]
-    if link_free is None:
-        link_free = noc_flight.make_link_free(T)
+    if rings is None:
+        rings = noc_flight.make_link_rings(net, T)
     if active is None:
         active = jnp.ones(K, dtype=bool)
     return noc_flight.flight(
-        NET, mesh_w, mesh_h, src, jnp.asarray(dst, jnp.int32),
-        jnp.asarray(depart, jnp.int64), flits, active, link_free,
+        net, mesh_w, mesh_h, src, jnp.asarray(dst, jnp.int32),
+        jnp.asarray(depart, jnp.int64), flits, active, rings,
         jnp.full(K, 500, jnp.int32))   # 2 GHz -> 500 ps/cycle
 
 
@@ -80,9 +88,86 @@ def test_carried_horizon_backpressures_next_batch():
     """Link horizons persist: a second batch arriving while the link is
     still busy from batch one waits for it (the queue model's memory)."""
     r1 = _fly([1], [0], [0], 8, 2, 2, 4)            # 8-flit occupancy
-    r2 = _fly([1], [0], [0], 8, 2, 2, 4, link_free=r1.link_free)
+    r2 = _fly([1], [0], [0], 8, 2, 2, 4, rings=r1.rings)
     assert int(r2.wait_ps[0]) == 8 * 500            # waits out batch 1
     assert int(r2.arrival[0]) == int(r1.arrival[0]) + 8 * 500
+
+
+@pytest.mark.parametrize("qtype, wait", [
+    ("basic", 14000), ("history_list", 0), ("history_tree", 0)])
+def test_later_batch_earlier_packet(qtype, wait):
+    """Batch one holds link (W, tile 1) over [10000, 14000); batch two's
+    one-flit packet reaches it at 0.  A history queue fits it into the idle
+    gap before the recorded interval; the basic model's carried horizon
+    makes it wait behind the later packet (the phantom convoy)."""
+    net = dataclasses.replace(NET, queue_model_type=qtype)
+    r1 = _fly([1], [0], [10000], 8, 2, 2, 4, net=net)
+    assert int(r1.wait_ps[0]) == 0
+    r2 = _fly([1], [0], [0], 1, 2, 2, 4, rings=r1.rings, net=net)
+    assert int(r2.wait_ps[0]) == wait
+    assert int(r2.arrival[0]) == wait + 2 * 500
+
+
+@pytest.mark.parametrize("qtype", QTYPES)
+def test_idle_mesh_is_zero_load_for_every_type(qtype):
+    """Packets whose paths share no link, and one that stays home, pay
+    exactly the hop-counter latency under every link queue model."""
+    net = dataclasses.replace(NET, queue_model_type=qtype)
+    src, dst = [0, 4, 8, 15, 5], [3, 7, 11, 12, 5]     # rows E, E, E, W
+    r = _fly(src, dst, [0, 100, 2000, 0, 7], 3, 4, 4, 16, net=net)
+    want = np.asarray([0, 100, 2000, 0, 7]) + np.asarray(noc.unicast_ps(
+        net, jnp.asarray(src), jnp.asarray(dst),
+        (3 * 64) // 8 - noc.PACKET_HEADER_BYTES,   # payload giving 3 flits
+        jnp.full(5, 500, jnp.int32), 4))
+    assert np.asarray(r.arrival).tolist() == want.tolist()
+    assert not np.asarray(r.wait_ps).any()
+
+
+def test_ring_wraps():
+    """A link's ring keeps its ``queue_slots`` latest intervals: the third
+    interval overwrites the first, which then no longer delays a packet
+    arriving inside it."""
+    net = dataclasses.replace(NET, queue_model_type="history_list",
+                              queue_slots=2)
+    link = noc_flight.DIR_W * 4 + 1                  # (W, tile 1)
+    rings = None
+    for dep in (0, 10000, 20000):                    # 8 flits: 4000 ps busy
+        r = _fly([1], [0], [dep], 8, 2, 2, 4, rings=rings, net=net)
+        rings = r.rings
+    assert int(rings.ptr[link]) == 3
+    assert np.asarray(rings.start[:, link]).tolist() == [20000, 10000]
+    assert np.asarray(rings.end[:, link]).tolist() == [24000, 14000]
+    late = _fly([1], [0], [1000], 1, 2, 2, 4, rings=rings, net=net)
+    assert int(late.wait_ps[0]) == 0
+    kept = _fly([1], [0], [11000], 1, 2, 2, 4, rings=rings, net=net)
+    assert int(kept.wait_ps[0]) == 3000
+
+
+def test_uncontended_networks_carry_no_link_queues():
+    """Without its queue model, or off the hop-by-hop mesh, a network has
+    zero-sized link rings: the hop-counter programs carry no new bytes."""
+    off = dataclasses.replace(NET, queue_model_enabled=False)
+    counter = dataclasses.replace(NET, model="emesh_hop_counter")
+    for net in (off, counter):
+        assert not noc_flight.contended(net)
+        rings = noc_flight.make_link_rings(net, 64)
+        assert all(a.size == 0 for a in rings)
+    rings = noc_flight.make_link_rings(NET, 64)
+    assert rings.start.shape == (100, 4 * 64)
+
+
+@pytest.mark.parametrize("qtype, over, slots", [
+    ("history_tree", None, 100), ("history_list", None, 100),
+    ("history_tree", 7, 7), ("basic", 7, 1)])
+def test_link_rings_take_the_configured_list_size(qtype, over, slots):
+    """Each link's ring holds [queue_model/<type>] max_list_size busy
+    intervals (100 in carbon_sim.cfg); basic keeps one horizon."""
+    sets = {"network/emesh_hop_by_hop/queue_model/type": qtype}
+    if over is not None:
+        sets[f"queue_model/{qtype}/max_list_size"] = over
+    net = make_params(16, **sets).net_memory
+    assert net.queue_slots == slots
+    assert noc_flight.make_link_rings(net, 16).start.shape == (slots, 64)
 
 
 def test_distinct_links_no_interference():

@@ -5,6 +5,7 @@ driver's host spans reach the profiler trace while the tracer is enabled
 
 import glob
 import os
+import re
 
 import pytest
 
@@ -57,16 +58,24 @@ def metadata_in_cache_key():
     (4, {"general/max_threads_per_core": 4},
      lambda: synth.gen_threads_oversubscribed(num_streams=8),
      {scopes.SCHEDULE}),
-], ids=["radix8", "radix8_ff_sampled", "threads"])
+    (8, {"network/memory": "emesh_hop_by_hop"}, _radix8,
+     {scopes.NOC_FLIGHT}),
+], ids=["radix8", "radix8_ff_sampled", "threads", "radix8_contended"])
 def test_scopes_reach_compiled_program(tiles, over, make_trace, extra):
     """The compiled megarun's HLO metadata carries exactly the scopes of
-    the phases this configuration runs."""
+    the phases this configuration runs: the contended mesh's flight loop
+    only where the memory network is contended, nested in memory
+    resolve."""
     params = _params(tiles, **over)
     sim = Simulator(params, make_trace())
     text = quantum._megarun_nodonate.lower(
         params, sim.state, sim.trace, 0).compile().as_text()
-    found = {s for s in scopes.PHASES if f"/{s}/" in text}
+    found = {s for s in scopes.PHASES + (scopes.NOC_FLIGHT,)
+             if f"/{s}/" in text}
     assert found == ALWAYS | extra
+    if scopes.NOC_FLIGHT in extra:
+        nested = f'/{scopes.RESOLVE_MEMORY}/[^"]*/{scopes.NOC_FLIGHT}/'
+        assert re.search(nested, text)
 
 
 def _host_spans(trace_dir, names):

@@ -2,15 +2,20 @@
 and NoC-link queueing — reference common/shared_models/queue_model*.{h,cc}:
 basic, history_list, history_tree, and the analytic m_g_1)."""
 
+from types import SimpleNamespace
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from graphite_tpu.engine.queue_models import (
-    VALID_TYPES, basic_ring, fcfs, fcfs_ring, mg1_delay, occupy, probe)
+    VALID_TYPES, basic_ring, fcfs_ring, mg1_delay, occupy, probe)
 
 
 def run_fcfs(resource, arrival, service, valid=None, free_at=None, n_res=4):
+    """FCFS over one carried horizon per resource (``basic_ring`` with no
+    moving average; the horizon is ring slot 0 of the ends, read back as
+    ``free_at``)."""
     resource = jnp.asarray(resource, dtype=jnp.int32)
     arrival = jnp.asarray(arrival, dtype=jnp.int64)
     service = jnp.asarray(service, dtype=jnp.int64)
@@ -22,7 +27,10 @@ def run_fcfs(resource, arrival, service, valid=None, free_at=None, n_res=4):
         free_at = jnp.zeros(n_res, dtype=jnp.int64)
     else:
         free_at = jnp.asarray(free_at, dtype=jnp.int64)
-    return fcfs(resource, arrival, service, valid, free_at)
+    q, _ = basic_ring(resource, arrival, service, valid,
+                      jnp.zeros((1, n_res), jnp.int64), free_at[None, :],
+                      jnp.zeros(n_res, jnp.int32))
+    return SimpleNamespace(delay=q.delay, end=q.end, free_at=q.ring_end[0])
 
 
 def test_no_contention():
