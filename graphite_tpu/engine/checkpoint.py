@@ -29,11 +29,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from graphite_tpu.engine.state import SimState, make_state, \
-    sharers_from_planes
+from graphite_tpu.engine.state import SimState, dir_word_from_2d, \
+    make_state, sharers_from_planes
 from graphite_tpu.params import SimParams
 
-_SCHEMA_VERSION = 29  # v29: per-link queue rings (link_mem/*,
+_SCHEMA_VERSION = 30  # v30: the packed directory words are stored as a
+#   flat, entry-major uint32 store (SimState.dir_word); a v26-v29
+#   [A, F] int64 dir_word converts on load (dir_word_from_2d);
+#   v29: per-link queue rings (link_mem/*,
 #   link_user/*: noc_flight.LinkRings) replace the carried link horizons
 #   (link_free_mem / link_free_user); a v26-v28 file restores with empty
 #   link rings (its horizons are dropped);
@@ -154,11 +157,11 @@ def _open_checkpoint(path: str):
 
 
 # v27 added ingest metadata WITHOUT touching state-leaf semantics, so
-# v26 (whole-trace) checkpoints restore unchanged, v28 only changed
-# the sharer store's layout, which converts on load, and v29's link rings
-# start empty; anything older predates the routed-resolve counter
-# semantics and is rejected.
-_COMPATIBLE_SCHEMAS = (26, 27, 28, 29)
+# v26 (whole-trace) checkpoints restore unchanged, v28 and v30 only
+# changed the sharer and directory stores' layouts, which convert on
+# load, and v29's link rings start empty; anything older predates the
+# routed-resolve counter semantics and is rejected.
+_COMPATIBLE_SCHEMAS = (26, 27, 28, 29, 30)
 
 
 def _check_schema(path: str, z) -> None:
@@ -192,7 +195,10 @@ def _load_leaves(path: str, z, template: SimState) -> SimState:
                 f"({type(e).__name__}: {e}) — truncated or corrupt") \
                 from e
         if planes and key == "dir_sharers":
+            # Files of schema <= 27 hold the [.., A, F] int64 dir_word.
             a = sharers_from_planes(a, z["dir_word"].shape[-2])
+        if key == "dir_word" and a.ndim > tmpl.ndim:
+            a = dir_word_from_2d(a)
         if a.shape != tmpl.shape:
             raise ValueError(
                 f"checkpoint field {key!r} shape {a.shape} != expected "

@@ -137,7 +137,7 @@ def _maybe_sample(params: SimParams, state: SimState) -> SimState:
     releases the same way — lax_barrier_sync_server.cc:157-159 notifying
     statistics_thread.cc; series list per statistics_manager.cc:41-114)."""
     from graphite_tpu.engine import cache as cachemod
-    from graphite_tpu.engine.state import dword_state
+    from graphite_tpu.engine.state import dir_live_entries
     S = state.stat_time.shape[0]
     interval = jnp.int64(params.stat_interval_ps)
     do = (state.boundary >= state.stat_next) & (state.stat_filled < S)
@@ -147,8 +147,7 @@ def _maybe_sample(params: SimParams, state: SimState) -> SimState:
         c = st.counters
         if stats_ring_enabled(params):
             if params.shared_l2:
-                live = jnp.sum(dword_state(st.dir_word) != 0,
-                               dtype=jnp.int64)
+                live = dir_live_entries(st.dir_word)
             else:
                 live = jnp.sum(cachemod.meta_state(st.l2.meta) != 0,
                                dtype=jnp.int64)

@@ -75,8 +75,9 @@ from graphite_tpu.engine.kernels.chain import CTRL_BYTES, J_OWN, _lat
 from graphite_tpu.engine.state import (
     PEND_BARRIER, PEND_CBC, PEND_COND, PEND_CSIG, PEND_EX_REQ, PEND_IFETCH,
     PEND_JOIN, PEND_MUTEX, PEND_NONE, PEND_RECV, PEND_SEND, PEND_SH_REQ,
-    PEND_START, SimState, TraceArrays, dword_owner, dword_pack, dword_stamp,
-    dword_state, dword_tag, dword_with_meta, sharer_words, sharers_add,
+    PEND_START, SimState, TraceArrays, dir_gather, dir_set, dword_owner,
+    dword_pack, dword_stamp, dword_state, dword_tag, dword_with_meta,
+    sharer_words, sharers_add,
     sharers_gather)
 from graphite_tpu.engine.vparams import VariantParams, variant_params
 from graphite_tpu.isa import DVFSModule, EventOp
@@ -97,7 +98,6 @@ _REC_EMPTY, _REC_REQ, _REC_VIC = 0, 1, 2
 
 # A stamp value no in-use directory entry carries (vkey sentinel).
 _NEVER = np.int32(2**31 - 1)
-_DROP = np.int32(2**30)   # out-of-bounds scatter index (mode="drop")
 
 _FLAG_KEYS = ("progress", "more_heads", "overflow", "done", "routed")
 
@@ -224,10 +224,10 @@ def _vic_apply(params: SimParams, st: SimState, valid, g_tile, vline, vdirty,
     directory slice — the shard-local port of resolve._dir_evict_notify
     (same probe, same meta rewrites, same merged sharer-subtract scatter,
     with tile-BIT geometry in GLOBAL ids and set indices local)."""
-    A = st.dir_word.shape[0]
+    A = params.directory.associativity
     W = sharer_words(params.num_tiles)
 
-    drow = st.dir_word[:, fidx_l].T                       # [R, A]
+    drow = dir_gather(st.dir_word, fidx_l, A)             # [R, A]
     dstate = dword_state(drow)
     match = (dword_tag(drow) == vline[:, None].astype(jnp.int64)) \
         & (dstate != _I) & valid[:, None]
@@ -253,8 +253,8 @@ def _vic_apply(params: SimParams, st: SimState, valid, g_tile, vline, vdirty,
     mask_s = drop_o & ~empty                               # O -> S, ownerless
     new_word = jnp.where(mask_i, dword_with_meta(word_way, _I, -1),
                          dword_with_meta(word_way, _S, -1))
-    fmeta = jnp.where(mask_i | mask_s, fidx_l, _DROP)
-    dir_word = st.dir_word.at[way, fmeta].set(new_word, mode="drop")
+    dir_word = dir_set(st.dir_word, A, mask_i | mask_s, fidx_l, way,
+                       new_word)
     # Merged sharer subtract: dead entries drop their whole row, live
     # S/O entries drop this tile's bit (modular add).
     dir_sharers = sharers_add(
@@ -285,9 +285,9 @@ def _routed_pass(params: SimParams, vp: VariantParams, st: SimState,
     S = shards
     TL = T // S
     P = params.miss_chain
-    A = st.dir_word.shape[0]
+    A = params.directory.associativity
     W = sharer_words(T)
-    ndsets = st.dir_word.shape[1] // TL
+    ndsets = st.dir_word.shape[0] // (2 * A * TL)
     FL = TL * ndsets
     C = min(cap, 2 * TL)          # per-(source, dest) record capacity
     R = S * C                     # records per home shard per iteration
@@ -389,7 +389,7 @@ def _routed_pass(params: SimParams, vp: VariantParams, st: SimState,
         h_issue = rec[:, 4]
         h_extra = rec[:, 5]
 
-        drow = st.dir_word[:, fidx_l].T                    # [R, A]
+        drow = dir_gather(st.dir_word, fidx_l, A)          # [R, A]
         dstate = dword_state(drow)
         dstamp = dword_stamp(drow)
         match = (dword_tag(drow) == rline[:, None]) & (dstate != _I)
@@ -446,10 +446,9 @@ def _routed_pass(params: SimParams, vp: VariantParams, st: SimState,
             | (act.owner_leg & (posr >= J_OWN)))
 
         # Directory apply.
-        fidx_w = jnp.where(serve, fidx_l, _DROP)
-        new_word = dword_pack(rline, st.round_ctr, act.new_state,
-                              act.new_owner)
-        dir_word = st.dir_word.at[way, fidx_w].set(new_word, mode="drop")
+        dir_word = dir_set(st.dir_word, A, serve, fidx_l, way,
+                           dword_pack(rline, st.round_ctr, act.new_state,
+                                      act.new_owner))
         dir_sharers = sharers_add(
             st.dir_sharers, A, W,
             rows=(serve, fidx_l, way, entry_row, act.new_sharers))

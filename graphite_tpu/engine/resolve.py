@@ -37,8 +37,9 @@ from graphite_tpu.engine.kernels import dispatch as kdispatch
 from graphite_tpu.engine.state import (
     PEND_BARRIER, PEND_CBC, PEND_COND, PEND_CSIG, PEND_EX_REQ, PEND_IFETCH,
     PEND_JOIN, PEND_MUTEX, PEND_NONE, PEND_RECV, PEND_SEND, PEND_SH_REQ,
-    PEND_START, SimState, dword_owner, dword_pack, dword_stamp, dword_state,
-    dword_tag, dword_with_meta, sharer_words, sharers_add, sharers_gather)
+    PEND_START, SimState, dir_gather, dir_set, dword_owner, dword_pack,
+    dword_stamp, dword_state, dword_tag, dword_with_meta, sharer_words,
+    sharers_add, sharers_gather)
 from graphite_tpu.engine.vparams import VariantParams, variant_params
 from graphite_tpu.isa import DVFSModule
 from graphite_tpu.obs import scopes
@@ -281,7 +282,7 @@ def chain_fast_pass(params: SimParams, vp: VariantParams, state: SimState,
         hidx = (dense.fmix64(line) % jnp.uint64(H)).astype(jnp.int32)
 
         # ---- directory entry rows at (home, dset) — ONE gather each
-        drow = state.dir_word[:, fidx].T                       # [T, A]
+        drow = dir_gather(state.dir_word, fidx, A)             # [T, A]
         dsharers = sharers_gather(state.dir_sharers, fidx, A, W)  # [T, A, W]
 
         queue_on = params.dram.queue_model_enabled
@@ -343,11 +344,9 @@ def chain_fast_pass(params: SimParams, vp: VariantParams, state: SimState,
 
         # ---- apply: directory entry + sharer-bitmap delta (winners
         # hold distinct (home, dset, way) slots by the election above)
-        fidx_w = jnp.where(serve, fidx, jnp.int32(2**30))
-        state = state._replace(dir_word=state.dir_word.at[
-            way, fidx_w].set(
-            dword_pack(line, state.round_ctr, co.new_state,
-                       co.new_owner), mode="drop"))
+        state = state._replace(dir_word=dir_set(
+            state.dir_word, A, serve, fidx, way,
+            dword_pack(line, state.round_ctr, co.new_state, co.new_owner)))
         # Reps rewrite their row (old + delta); combining members add
         # their own bit on top of the rep's rewritten row — ONE merged
         # scatter-add, as in the round loop.
@@ -719,7 +718,7 @@ def resolve_memory(params: SimParams, vp: VariantParams,
 
         # ---- directory-cache probe at (home, dset), via the flat
         # (home*ndsets + dset) index — ONE gather for the whole entry
-        drow = state.dir_word[:, fidx].T                     # [T, A]
+        drow = dir_gather(state.dir_word, fidx, A)           # [T, A]
         dstate = dword_state(drow)
         dstamp = dword_stamp(drow)
         match = (dword_tag(drow) == line[:, None].astype(jnp.int32)) \
@@ -1214,15 +1213,14 @@ def resolve_memory(params: SimParams, vp: VariantParams,
         # replacement recency is the scattered round stamp (timestamp LRU,
         # like engine/cache.py — the old code maintained rank permutations
         # with dense [T, T, A] merges).
-        fidx_w = jnp.where(win, fidx, jnp.int32(2**30))
         # (Combined SH winners of one line write identical packed words,
         # so their colliding scatters are safe; the sharer bitmap is the
         # one per-winner-distinct field — the line's rep rewrites the row,
         # then the other combined winners add their disjoint bits on top.)
-        state = state._replace(
-            dir_word=state.dir_word.at[way, fidx_w].set(
-                dword_pack(line, state.round_ctr, act.new_state,
-                           act.new_owner), mode="drop"))
+        state = state._replace(dir_word=dir_set(
+            state.dir_word, A, win, fidx, way,
+            dword_pack(line, state.round_ctr, act.new_state,
+                       act.new_owner)))
         # Sharer-bitmap rewrite as modular delta-adds: the slot's current
         # row is known (the hit entry's words, or the victim's for a fresh
         # alloc), so adding (new - old) per u32 word lands the new row
@@ -1638,7 +1636,7 @@ class _VictimProbe:
         self.vdset = dir_set_of_line(params, vtag)
         self.vfidx = (self.vhome * ndsets + self.vdset).astype(jnp.int32)
         vfidx = self.vfidx
-        drow = state.dir_word[:, vfidx].T                   # [T, A]
+        drow = dir_gather(state.dir_word, vfidx, A)         # [T, A]
         dstate = dword_state(drow)
         match = (dword_tag(drow) == vtag[:, None].astype(jnp.int32)) \
             & (dstate != I) & valid[:, None]
@@ -1662,11 +1660,9 @@ class _VictimProbe:
         """Rewrite the matched entry's (state, owner) where ``mask``
         (tag + stamp preserved from the gathered word; callers pass
         disjoint masks, so each entry is written at most once)."""
-        f = jnp.where(mask, self.vfidx, jnp.int32(2**30))
-        return state._replace(
-            dir_word=state.dir_word.at[self.way, f].set(
-                dword_with_meta(self.word_way, new_state, new_owner),
-                mode="drop"))
+        return state._replace(dir_word=dir_set(
+            state.dir_word, self.assoc, mask, self.vfidx, self.way,
+            dword_with_meta(self.word_way, new_state, new_owner)))
 
     def set_meta2(self, state: SimState, mask_a, state_a, owner_a,
                   mask_b, state_b, owner_b):
@@ -1677,9 +1673,9 @@ class _VictimProbe:
         new = jnp.where(mask_a,
                         dword_with_meta(self.word_way, state_a, owner_a),
                         dword_with_meta(self.word_way, state_b, owner_b))
-        f = jnp.where(mask_a | mask_b, self.vfidx, jnp.int32(2**30))
-        return state._replace(
-            dir_word=state.dir_word.at[self.way, f].set(new, mode="drop"))
+        return state._replace(dir_word=dir_set(
+            state.dir_word, self.assoc, mask_a | mask_b, self.vfidx,
+            self.way, new))
 
     def clear_bit(self, state: SimState, mask):
         """Clear the dropping tile's sharer bit where ``mask`` (guarded

@@ -201,8 +201,8 @@ _DIR_OWNER_SHIFT = 3
 #                   correctness — same argument as cache.py STAMP_BITS)
 #     bits 33..63   tag (31-bit line id; frontend asserts addr < 2^37)
 #
-# Bits 0..15 are exactly the legacy int32 "meta" layout, so
-# dir_meta_state/dir_meta_owner keep working on the `dir_meta` view.
+# SimState.dir_word stores each word as two uint32 halves (dir_gather,
+# dir_set); dir_word_view gives the [A, F] int64 words back.
 DIR_STAMP_BITS = 17
 _DIR_STAMP_SHIFT = 16
 _DIR_STAMP_FIELD = (1 << DIR_STAMP_BITS) - 1
@@ -241,22 +241,6 @@ def dword_with_meta(word, state, owner):
     return (word & ~jnp.int64(_DIR_META_MASK)) \
         | ((jnp.asarray(owner, jnp.int64) + 1) << _DIR_OWNER_SHIFT) \
         | jnp.asarray(state, jnp.int64)
-
-
-def dir_pack(state, owner, lru=0):
-    """Legacy int32 'meta' word (state | owner+1 << 3) — the low 16 bits
-    of the packed dir_word; kept for tests/tools."""
-    del lru
-    return (jnp.asarray(state, jnp.int32)
-            | ((jnp.asarray(owner, jnp.int32) + 1) << _DIR_OWNER_SHIFT))
-
-
-def dir_meta_state(meta):
-    return meta & 7
-
-
-def dir_meta_owner(meta):
-    return ((meta >> _DIR_OWNER_SHIFT) & ((1 << _DIR_OWNER_BITS) - 1)) - 1
 
 
 class SimState(NamedTuple):
@@ -322,7 +306,15 @@ class SimState(NamedTuple):
     # [.., T, dsets] layout forced XLA to materialize a full-array reshape
     # copy per conflict round (profiled at ~4.5 ms per round on the 512 MB
     # 1024-tile sharer bitmap).
-    dir_word: jnp.ndarray     # [dassoc, T*dsets] int64 packed entries
+    dir_word: jnp.ndarray     # [T*dsets * dassoc * 2] uint32 packed
+    #   entries, flat and entry-major: entry (fidx, way) lives at
+    #   (fidx*dassoc + way)*2, word 0 the low 32 bits of its dword_pack
+    #   word, word 1 the high.  Flat and u32 for the reasons dir_sharers
+    #   gives below (as a [dassoc, F] int64 array each conflict round
+    #   relaid out all of it, 128 MiB at 1024 tiles, per u32 half);
+    #   entry-major, so an entry's ways are one run of 2*dassoc words.
+    #   Read and written only through dir_gather/dir_set;
+    #   dir_word_view gives the [A, F] int64 view.
     dir_sharers: jnp.ndarray  # [T*dsets * dassoc * 2W] uint32 full-map
     #   sharer bitmaps, flat and entry-major: word j of entry (fidx, way)
     #   lives at (fidx*dassoc + way)*2W + j and holds bitmap bits
@@ -551,21 +543,6 @@ class SimState(NamedTuple):
                            .set(self.done))
         return self.done.all()
 
-    # Unpacked directory views (tests/tools; the engine reads dir_word).
-    @property
-    def dir_tags(self) -> jnp.ndarray:
-        return dword_tag(self.dir_word)
-
-    @property
-    def dir_meta(self) -> jnp.ndarray:
-        """Legacy int32 meta view (state | owner+1 << 3) — feed to
-        dir_meta_state / dir_meta_owner."""
-        return (self.dir_word & _DIR_META_MASK).astype(jnp.int32)
-
-    @property
-    def dir_stamp(self) -> jnp.ndarray:
-        return dword_stamp(self.dir_word)
-
 
 def sharer_words(num_tiles: int) -> int:
     """uint64 words per full-map sharer row."""
@@ -585,25 +562,49 @@ def _split_words(rows: jnp.ndarray) -> jnp.ndarray:
     return halves.reshape(rows.shape[:-1] + (-1,))
 
 
+_ROW = 128    # u32 words per row of a flat store's [R, 128] gather view
+
+
+def _gather_runs(store: jnp.ndarray, start: jnp.ndarray, n: int
+                 ) -> jnp.ndarray:
+    """[N] word offsets, each a multiple of ``n`` -> the [N, n] u32 runs
+    of a flat store that start there.
+
+    Where ``n`` divides 128 words or 128 divides ``n``, the store is read
+    as [R, 128] rows (a bitcast of its tiled 1-D layout, no copy) by a
+    gather of whole rows, one index per row, and each run is picked out
+    of its row by a mask.  XLA:TPU pays a gather per index: for 1,024
+    runs of 32 words, 16 us a call against 652 us for the element gather
+    (one index per word) and 1.2 ms for the slice gather (a loop over
+    runs), on a v5e.  Other run lengths take the element gather."""
+    start = start.astype(jnp.int32)
+    if store.shape[0] % _ROW == 0 and _ROW % n == 0:
+        per = _ROW // n
+        rows = store.reshape(-1, _ROW)[start // _ROW]              # [N, 128]
+        pick = (start % _ROW // n)[:, None] \
+            == jnp.arange(per, dtype=jnp.int32)
+        return jnp.sum(jnp.where(pick[:, :, None],
+                                 rows.reshape(-1, per, n), jnp.uint32(0)),
+                       axis=1, dtype=jnp.uint32)
+    if store.shape[0] % _ROW == 0 and n % _ROW == 0:
+        r = start[:, None] // _ROW + jnp.arange(n // _ROW, dtype=jnp.int32)
+        return store.reshape(-1, _ROW)[r].reshape(-1, n)
+    return store[start[:, None] + jnp.arange(n, dtype=jnp.int32)]
+
+
 def sharers_gather(sh: jnp.ndarray, fidx: jnp.ndarray, assoc: int,
                    words: int, way: Optional[jnp.ndarray] = None
                    ) -> jnp.ndarray:
     """[N] flat entry ids -> [N, assoc, words] uint64 sharer rows, or
-    with ``way`` ([N]) the [N, words] row of that way alone.
-
-    All ways are one contiguous slice per entry; one way is an element
-    gather of its 2*words words, which XLA:TPU runs as one gather op
-    (the slice gather is a loop over entries: 20x slower per call at 64
-    tiles, 1.8x at 1024, on a v5e)."""
+    with ``way`` ([N]) the [N, words] row of that way alone: one run of
+    contiguous words per entry either way (_gather_runs)."""
     w32 = 2 * words
     fidx = fidx.astype(jnp.int32)
     if way is None:
-        n = assoc * w32
-        block = jax.vmap(lambda f: jax.lax.dynamic_slice(sh, (f * n,), (n,)))(
-            fidx)
+        block = _gather_runs(sh, fidx * (assoc * w32), assoc * w32)
         return _join_words(block.reshape(fidx.shape[0], assoc, w32))
     base = (fidx * assoc + way.astype(jnp.int32)) * w32
-    return _join_words(sh[base[:, None] + jnp.arange(w32, dtype=jnp.int32)])
+    return _join_words(_gather_runs(sh, base, w32))
 
 
 def sharers_add(sh: jnp.ndarray, assoc: int, words: int, *, rows=None,
@@ -643,9 +644,61 @@ def sharers_add(sh: jnp.ndarray, assoc: int, words: int, *, rows=None,
 def dir_sharers_view(state: "SimState", assoc: int) -> jnp.ndarray:
     """Flat sharer store -> [A, F, W] uint64 word-minor view (for tests
     and tools; the engine reads rows through sharers_gather)."""
-    F = state.dir_word.shape[-1]
+    F = state.dir_word.shape[-1] // (2 * assoc)
     rows = _join_words(state.dir_sharers.reshape(F, assoc, -1))
     return jnp.moveaxis(rows, 1, 0)
+
+
+def _as_int64(words: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.bitcast_convert_type(words, jnp.int64)
+
+
+def dir_gather(dw: jnp.ndarray, fidx: jnp.ndarray, assoc: int
+               ) -> jnp.ndarray:
+    """[N] flat entry ids -> [N, assoc] int64 packed words of every way:
+    each entry's run of 2*assoc u32 words (_gather_runs), whose halves
+    are joined on the gathered rows alone."""
+    w32 = 2 * assoc
+    return _as_int64(_join_words(
+        _gather_runs(dw, fidx.astype(jnp.int32) * w32, w32)))
+
+
+def dir_set(dw: jnp.ndarray, assoc: int, mask, fidx, way, word
+            ) -> jnp.ndarray:
+    """Write the packed int64 ``word`` ([N]) into entry (fidx, way) where
+    ``mask``: both u32 halves in ONE 1-D scatter.  Masked-off writes index
+    one past the end and drop; writes that meet in one entry must carry
+    the same word."""
+    base = (fidx.astype(jnp.int32) * assoc + way.astype(jnp.int32)) * 2
+    idx = jnp.where(mask[:, None],
+                    base[:, None] + jnp.arange(2, dtype=jnp.int32),
+                    dw.shape[0])
+    halves = _split_words(jax.lax.bitcast_convert_type(
+        jnp.asarray(word, jnp.int64), jnp.uint64)[:, None])
+    return dw.at[idx.reshape(-1)].set(halves.reshape(-1), mode="drop")
+
+
+def dir_live_entries(dw: jnp.ndarray) -> jnp.ndarray:
+    """Entries not in I: the state bits (0..2) of each entry's low word,
+    the even words of the store, without a relayout of it."""
+    even = jnp.arange(dw.shape[0], dtype=jnp.int32) % 2 == 0
+    return jnp.sum(((dw & 7) != 0) & even, dtype=jnp.int64)
+
+
+def dir_word_view(dw: jnp.ndarray, assoc: int) -> jnp.ndarray:
+    """Flat directory store -> [A, F] int64 packed words (for tests and
+    tools; the engine reads entries through dir_gather)."""
+    return _as_int64(_join_words(dw.reshape(-1, 2 * assoc))).T
+
+
+def dir_word_from_2d(words: np.ndarray) -> np.ndarray:
+    """The [..., A, F] int64 dir_word of checkpoint schemas <= 29 -> the
+    flat [..., F*A*2] uint32 store (leading axes kept)."""
+    *lead, _a, _f = words.shape
+    rows = np.moveaxis(words, -2, -1).astype(np.uint64)     # [..., F, A]
+    halves = np.stack([rows & np.uint64(0xFFFFFFFF), rows >> np.uint64(32)],
+                      axis=-1).astype(np.uint32)
+    return halves.reshape(*lead, -1)
 
 
 def sharers_from_planes(planes: np.ndarray, assoc: int) -> np.ndarray:
@@ -747,9 +800,10 @@ def make_state(params: SimParams,
             f"({(1 << _DIR_OWNER_BITS) - 2} max); widen _DIR_OWNER_BITS")
     if channel_depth <= 0:
         channel_depth = params.channel_depth
-    d_shape = (params.directory.associativity,
-               T * params.directory.num_sets)
-    n_sharer = d_shape[1] * d_shape[0] * 2 * sharer_words(T)
+    n_entry = params.directory.associativity * T * params.directory.num_sets
+    n_sharer = n_entry * 2 * sharer_words(T)
+    # dir_word's 2 words per entry are never more than the sharer store's
+    # 2W, so this one check keeps both flat stores inside int32 indexing.
     per_device = n_sharer // (params.tile_shards
                               if params.shard_state == "resident" else 1)
     if per_device >= 2**31:
@@ -781,7 +835,7 @@ def make_state(params: SimParams,
             else cachemod.make_cache(T, params.l2)),
         period_ps=jnp.asarray(init_periods(params)),
         # I-state, owner -1, tag/stamp 0 packs to the all-zeros word.
-        dir_word=jnp.zeros(d_shape, dtype=jnp.int64),
+        dir_word=jnp.zeros(n_entry * 2, dtype=jnp.uint32),
         dir_sharers=jnp.zeros(n_sharer, dtype=jnp.uint32),
         mq_req=jnp.zeros((params.miss_chain, T), dtype=jnp.int64),
         mq_delta=jnp.zeros((params.miss_chain, T), dtype=jnp.int64),

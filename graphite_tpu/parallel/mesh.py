@@ -115,7 +115,7 @@ _TILE_AXIS_BY_FIELD = {
     #   win_base/win_seat and the round-9 chain_fanout_served/
     #   chain_fallback counters are tile-leading, covered by the
     #   default axis-0 rule below)
-    "dir_word": 1,                   # [A, T*dsets] (tile-major flat)
+    "dir_word": 0,                   # [T*dsets*A*2] u32 (entry-major flat)
     "dir_sharers": 0,                # [T*dsets*A*2W] u32 (entry-major flat)
     "ch_time": 1,                    # [D, T, T]
     "mq_req": 1,                     # [P, T] banked miss chains

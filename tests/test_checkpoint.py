@@ -350,6 +350,14 @@ def test_resident_old_schema_rejected(tmp_path):
         Simulator(params, trace).restore_checkpoint(old)
 
 
+def _dir_word_2d(flat, assoc):
+    """The flat uint32 directory store -> the [A, F] int64 dir_word that
+    checkpoints of schemas 26-29 carry (entry (f, way) at [way, f])."""
+    halves = flat.reshape(-1, assoc, 2).astype(np.uint64)
+    words = halves[..., 0] | (halves[..., 1] << np.uint64(32))   # [F, A]
+    return np.ascontiguousarray(words.T).view(np.int64)
+
+
 def test_v27_sharer_planes_restore_identical(tmp_path):
     """Schema 28 stores the sharer bitmap as flat uint32 words; a v27
     checkpoint's [W*A, F] uint64 planes (plane (w, way) at row w*A + way)
@@ -372,6 +380,7 @@ def test_v27_sharer_planes_restore_identical(tmp_path):
     flat = doctored["dir_sharers"]
     assert flat.dtype == np.uint32 and flat.any()
     A = params.directory.associativity
+    doctored["dir_word"] = _dir_word_2d(doctored["dir_word"], A)
     F = doctored["dir_word"].shape[1]
     words = flat.reshape(F, A, -1, 2).astype(np.uint64)
     rows = words[..., 0] | (words[..., 1] << np.uint64(32))     # [F, A, W]
@@ -432,3 +441,42 @@ def test_v28_restores_with_empty_link_rings(tmp_path):
     got = restored(v28, "v28.npz")
     for k in keys:
         assert got[k].shape == saved[k].shape and not got[k].any(), k
+
+
+def test_v29_dir_word_restores_identical(tmp_path):
+    """Schema 30 stores the packed directory words as flat uint32 halves;
+    a v29 checkpoint's [A, F] int64 dir_word converts on load, bit for
+    bit, and the resumed run equals the unbroken one."""
+    cfg = load_config()
+    cfg.set("general/total_cores", 8)
+    params = SimParams.from_config(cfg)
+    trace = synth.gen_radix(num_tiles=8, keys_per_tile=16, radix=16, seed=5)
+
+    full = Simulator(params, trace)
+    s_full = full.run()
+
+    half = Simulator(params, trace)
+    half.run(max_steps=2)
+    ck = str(tmp_path / "v30.npz")
+    half.save_checkpoint(ck)
+    with np.load(ck) as z:
+        doctored = {k: z[k] for k in z.files}
+    flat = doctored["dir_word"]
+    A = params.directory.associativity
+    assert flat.dtype == np.uint32 and flat.ndim == 1
+    words = _dir_word_2d(flat, A)
+    assert words.shape == (A, 8 * params.directory.num_sets)
+    assert (words & 7).any()                         # live entries to carry
+    doctored["dir_word"] = words
+    doctored["__meta_schema"] = np.int64(29)
+    old = str(tmp_path / "v29.npz")
+    with open(old, "wb") as f:
+        np.savez(f, **doctored)
+
+    resumed = Simulator(params, trace)
+    resumed.restore_checkpoint(old)
+    np.testing.assert_array_equal(np.asarray(resumed.state.dir_word), flat)
+    s_res = resumed.run()
+    assert s_full.completion_time_ps == s_res.completion_time_ps
+    for f, a in s_full.counters.items():
+        assert np.array_equal(a, s_res.counters[f]), f

@@ -40,7 +40,7 @@ def _store(planes):
 
 def _view(sh, T):
     state = SimpleNamespace(dir_sharers=sh,
-                            dir_word=np.zeros((A, T * DSETS), np.int64))
+                            dir_word=np.zeros(T * DSETS * A * 2, np.uint32))
     return np.asarray(dir_sharers_view(state, A))
 
 

@@ -58,8 +58,9 @@ def test_shared_readers_sharer_bitmap():
     assert int(c["dir_sh_req"].sum()) == 8 * 8
     assert int(c["dir_invalidations"].sum()) == 0
     # the directory must now record all 8 tiles as sharers of each line
-    from graphite_tpu.engine.state import dir_meta_state
-    dstate = np.asarray(dir_meta_state(sim.state.dir_meta))  # [A, T, dsets]
+    from graphite_tpu.engine.state import dir_word_view, dword_state
+    dstate = np.asarray(dword_state(dir_word_view(
+        sim.state.dir_word, sim.params.directory.associativity)))  # [A, F]
     from graphite_tpu.engine.state import dir_sharers_view
     dsharers = np.asarray(dir_sharers_view(
         sim.state, sim.params.directory.associativity))
@@ -110,8 +111,9 @@ def test_write_invalidates_sharers():
     # tile 0's final read downgraded writer 2's M entry: S, sharers {0, 2},
     # one owner writeback
     assert int(c["dir_writebacks"].sum()) == 1
-    from graphite_tpu.engine.state import dir_meta_state
-    dstate = np.asarray(dir_meta_state(sim.state.dir_meta))  # [A, T, dsets]
+    from graphite_tpu.engine.state import dir_word_view, dword_state
+    dstate = np.asarray(dword_state(dir_word_view(
+        sim.state.dir_word, sim.params.directory.associativity)))  # [A, F]
     from graphite_tpu.engine.state import dir_sharers_view
     dsharers = np.asarray(dir_sharers_view(
         sim.state, sim.params.directory.associativity))

@@ -15,7 +15,8 @@ import numpy as np
 from graphite_tpu.config import load_config
 from graphite_tpu.engine import cache as cachemod
 from graphite_tpu.engine.sim import Simulator, run_simulation
-from graphite_tpu.engine.state import (dir_meta_owner, dir_meta_state)
+from graphite_tpu.engine.state import (dir_word_view, dword_owner,
+                                       dword_state)
 from graphite_tpu.events.schema import TraceBuilder
 from graphite_tpu.events import synth
 from graphite_tpu.params import SimParams
@@ -23,6 +24,12 @@ from graphite_tpu.params import SimParams
 MOSI = "pr_l1_pr_l2_dram_directory_mosi"
 MSI = "pr_l1_pr_l2_dram_directory_msi"
 
+
+
+def _dir_words(sim):
+    """[A, F] packed directory words of a finished simulation."""
+    return dir_word_view(sim.state.dir_word,
+                         sim.params.directory.associativity)
 
 def make_params(tiles=4, protocol=MOSI, **over):
     cfg = load_config()
@@ -77,8 +84,8 @@ def test_o_entry_state_and_owner_kept():
     trace = _producer_reader_trace(readers=2)
     sim = Simulator(params, trace)
     sim.run()
-    dstate = np.asarray(dir_meta_state(sim.state.dir_meta))
-    downer = np.asarray(dir_meta_owner(sim.state.dir_meta))
+    dstate = np.asarray(dword_state(_dir_words(sim)))
+    downer = np.asarray(dword_owner(_dir_words(sim)))
     o_entries = dstate == cachemod.O
     assert o_entries.sum() == 1
     assert downer[o_entries][0] == 0          # tile 0 still owns the line
@@ -114,8 +121,8 @@ def test_write_after_o_flushes_owner_and_sharers():
     assert int(c["dram_writes"].sum()) == 0
     # tile 0's post-flush read missed (copy was flushed to I)
     assert int(c["l1d_read_miss"][0]) == 1
-    dstate = np.asarray(dir_meta_state(sim.state.dir_meta))
-    downer = np.asarray(dir_meta_owner(sim.state.dir_meta))
+    dstate = np.asarray(dword_state(_dir_words(sim)))
+    downer = np.asarray(dword_owner(_dir_words(sim)))
     o_entries = dstate == cachemod.O
     assert o_entries.sum() == 1
     assert downer[o_entries][0] == 2      # final owner is the last writer
@@ -137,8 +144,8 @@ def test_owner_upgrade_in_place():
     s = sim.run()
     c = counters_np(s)
     assert int(c["dir_invalidations"].sum()) == 1
-    dstate = np.asarray(dir_meta_state(sim.state.dir_meta))
-    downer = np.asarray(dir_meta_owner(sim.state.dir_meta))
+    dstate = np.asarray(dword_state(_dir_words(sim)))
+    downer = np.asarray(dword_owner(_dir_words(sim)))
     m_entries = dstate == cachemod.M
     assert m_entries.sum() == 1
     assert downer[m_entries][0] == 0
@@ -159,8 +166,8 @@ def test_mosi_invariants_under_contention():
     sim = Simulator(params, trace)
     s = sim.run()
     assert s.to_dict()["all_done"]
-    dstate = np.asarray(dir_meta_state(sim.state.dir_meta))
-    downer = np.asarray(dir_meta_owner(sim.state.dir_meta))
+    dstate = np.asarray(dword_state(_dir_words(sim)))
+    downer = np.asarray(dword_owner(_dir_words(sim)))
     # M and O entries always carry a live owner
     assert np.all(downer[dstate == cachemod.M] >= 0)
     assert np.all(downer[dstate == cachemod.O] >= 0)

@@ -14,7 +14,7 @@ import numpy as np
 from graphite_tpu.config import load_config
 from graphite_tpu.engine import cache as cachemod
 from graphite_tpu.engine.sim import Simulator, run_simulation
-from graphite_tpu.engine.state import dir_meta_owner, dir_meta_state
+from graphite_tpu.engine.state import dir_word_view, dword_owner, dword_state
 from graphite_tpu.events.schema import TraceBuilder
 from graphite_tpu.events import synth
 from graphite_tpu.params import SimParams
@@ -22,6 +22,12 @@ from graphite_tpu.params import SimParams
 SH_MSI = "pr_l1_sh_l2_msi"
 SH_MESI = "pr_l1_sh_l2_mesi"
 
+
+
+def _dir_words(sim):
+    """[A, F] packed directory words of a finished simulation."""
+    return dir_word_view(sim.state.dir_word,
+                         sim.params.directory.associativity)
 
 def make_params(tiles=4, protocol=SH_MSI, **over):
     cfg = load_config()
@@ -85,7 +91,7 @@ def test_mesi_second_reader_downgrades_owner():
     s = sim.run()
     c = counters_np(s)
     assert int(c["dir_writebacks"].sum()) == 1
-    dstate = np.asarray(dir_meta_state(sim.state.dir_meta))
+    dstate = np.asarray(dword_state(_dir_words(sim)))
     from graphite_tpu.engine.state import dir_sharers_view
     dsharers = np.asarray(dir_sharers_view(
         sim.state, sim.params.directory.associativity))
@@ -111,10 +117,10 @@ def test_write_invalidates_sharers_shared_l2():
     assert int(c["dir_invalidations"].sum()) == 2
     assert int(c["l1d_read_miss"][0]) == 2
     # final read downgraded writer 2's M entry -> slice-dirty O
-    dstate = np.asarray(dir_meta_state(sim.state.dir_meta))
+    dstate = np.asarray(dword_state(_dir_words(sim)))
     o_entries = dstate == cachemod.O
     assert o_entries.sum() == 1
-    downer = np.asarray(dir_meta_owner(sim.state.dir_meta))
+    downer = np.asarray(dword_owner(_dir_words(sim)))
     assert downer[o_entries][0] == -1          # dirty at slice, no L1 owner
     # no DRAM data traffic beyond the cold fill
     assert int(c["dram_reads"].sum()) == 1
@@ -145,7 +151,7 @@ def test_dirty_l1_eviction_flushes_to_slice():
     assert int(c["dram_reads"].sum()) == assoc + 1   # cold fills only
     assert int(c["dram_writes"].sum()) == 0
     assert int(c["dir_writebacks"].sum()) == 0       # no owner flush legs
-    dstate = np.asarray(dir_meta_state(sim.state.dir_meta))
+    dstate = np.asarray(dword_state(_dir_words(sim)))
     # base line's entry: O with sharer {1} after the read
     assert (dstate == cachemod.O).sum() >= 1
 
@@ -157,8 +163,8 @@ def test_sh_l2_invariants_under_contention():
         sim = Simulator(params, trace)
         s = sim.run()
         assert s.to_dict()["all_done"], proto
-        dstate = np.asarray(dir_meta_state(sim.state.dir_meta))
-        downer = np.asarray(dir_meta_owner(sim.state.dir_meta))
+        dstate = np.asarray(dword_state(_dir_words(sim)))
+        downer = np.asarray(dword_owner(_dir_words(sim)))
         # M/E entries carry exactly one live L1 owner; S/O/I never do
         assert np.all(downer[dstate == cachemod.M] >= 0), proto
         assert np.all(downer[dstate == cachemod.E] >= 0), proto

@@ -97,21 +97,40 @@ def _relayouts(hlo, sizes):
     return found
 
 
-def test_resolve_keeps_sharer_store_layout(topo):
-    """Memory resolve reads and writes the flat uint32 sharer store in
-    place: no dynamic-update-slice and no transposing copy of an array of
-    its size, in u32 words or in the u64 words of the 2-D plane layout
-    it replaced (whose relayout took 72% of a 1,024-tile round).  At 128
-    tiles, where those counts differ from dir_word's; at 64 they match."""
+@pytest.fixture(scope="module")
+def resolve_t128(topo):
+    """(state, HLO text) of memory resolve compiled for one v5e at 128
+    tiles, where the sharer store's element counts differ from the
+    directory words' old [A, F] count (at 64 they match)."""
     from graphite_tpu.engine.vparams import variant_params
 
     one = SingleDeviceSharding(topo.devices[0])
     params = workload_params(128, {})
     st = make_state(params, has_capi=False)
-    n = st.dir_sharers.size
     vp = variant_params(params)
     hlo = jax.jit(lambda s: resolve.resolve_memory(params, vp, s)).lower(
         _shapes(st, lambda *_: one)).compile().as_text()
+    return st, hlo
+
+
+def test_resolve_keeps_sharer_store_layout(resolve_t128):
+    """Memory resolve reads and writes the flat uint32 sharer store in
+    place: no dynamic-update-slice and no transposing copy of an array of
+    its size, in u32 words or in the u64 words of the 2-D plane layout
+    it replaced (whose relayout took 72% of a 1,024-tile round)."""
+    st, hlo = resolve_t128
+    n = st.dir_sharers.size
+    assert _relayouts(hlo, {n, n // 2}) == []
+
+
+def test_resolve_keeps_dir_word_layout(resolve_t128):
+    """The same for the flat uint32 directory-word store: nothing of its
+    2*A*F u32 words, nor of the A*F int64 words of the [A, F] array it
+    replaced (each of whose u32 halves was relaid out per conflict round:
+    four transposing copies at 128 tiles, four loops of dynamic-update-
+    slices at 1,024)."""
+    st, hlo = resolve_t128
+    n = st.dir_word.size
     assert _relayouts(hlo, {n, n // 2}) == []
 
 
